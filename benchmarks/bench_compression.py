@@ -24,7 +24,10 @@ to the architecture's analytic ``param_count``; the JSON records both
 the measured and the extrapolated figures.  Gemma cells are tier-2: run
 with ``--model gemma`` (CI runs ``--model small`` only).
 
-Results land in ``results/BENCH_compression.json``.
+Results land in ``results/BENCH_compression.json`` (experiment traces
+under ``results/compression_traces/``).  The parent process never
+touches JAX (an accelerator belongs to one process at a time): every
+measurement runs in a worker subprocess, one at a time.
 
 Run: ``PYTHONPATH=src python -m benchmarks.bench_compression``
 """
@@ -42,6 +45,7 @@ import numpy as np
 
 RESULTS = Path(__file__).resolve().parent.parent / "results"
 OUT = RESULTS / "BENCH_compression.json"
+TRACES = RESULTS / "compression_traces"
 
 # (name, scheme, topk_ratio)
 SCHEMES = (
@@ -141,17 +145,26 @@ def _merge_worker(k: int, p: int) -> None:
     print(json.dumps({"devices": devices, "wall_s": wall}))
 
 
+def _run_worker(*argv, env=None):
+    """Run one measurement in a fresh worker process; echo its progress
+    lines and return the JSON record on its last line."""
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmarks.bench_compression", *argv],
+        capture_output=True, text=True, env=env or dict(os.environ),
+        check=True)
+    lines = proc.stdout.strip().splitlines()
+    for line in lines[:-1]:
+        print(line, flush=True)
+    return json.loads(lines[-1])
+
+
 def _bench_merge(k: int, p: int) -> dict:
     out = {}
     for n in MESH_SIZES:
         env = dict(os.environ)
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                             + f" --xla_force_host_platform_device_count={n}")
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_compression",
-             "--merge-worker", str(k), str(p)],
-            capture_output=True, text=True, env=env, check=True)
-        rec = json.loads(proc.stdout.strip().splitlines()[-1])
+        rec = _run_worker("--merge-worker", str(k), str(p), env=env)
         out[str(n)] = rec["wall_s"]
         print(f"  merge K={k} P={p} devices={n}: {rec['wall_s']:.4f}s")
     return out
@@ -160,7 +173,7 @@ def _bench_merge(k: int, p: int) -> dict:
 # ----------------------------------------------------------------------
 # small-CNN cells: full experiment per scheme + codec micro-bench
 # ----------------------------------------------------------------------
-def _small_cells(rounds: int, seed: int, tmpdir: Path) -> dict:
+def _small_cells(rounds: int, seed: int, trace_dir: Path) -> dict:
     import jax
     from repro.data import label_sorted_shards, make_image_classification
     from repro.data.synthetic import ArrayDataset
@@ -185,7 +198,7 @@ def _small_cells(rounds: int, seed: int, tmpdir: Path) -> dict:
 
     cells = {}
     for name, scheme, ratio in SCHEMES:
-        trace = tmpdir / f"small_{scheme}_{ratio}.jsonl"
+        trace = trace_dir / f"small_{scheme}_{ratio}.jsonl"
         cfg = ExperimentConfig(
             strategy="fedlesscan", n_rounds=rounds,
             clients_per_round=COHORT, eval_every=0, seed=seed,
@@ -218,8 +231,7 @@ def _small_cells(rounds: int, seed: int, tmpdir: Path) -> dict:
         print(f"small/{name:10s} bytes/round={bytes_per_round:12.0f} "
               f"ratio={cells[name]['compression_ratio']:7.1f}x "
               f"acc={res.final_accuracy:.3f}")
-    return {"cells": cells,
-            "merge_wall_s": _bench_merge(COHORT, P)}
+    return {"cells": cells, "param_count": P}
 
 
 # ----------------------------------------------------------------------
@@ -261,8 +273,7 @@ def _gemma_cells(seed: int, shards: int) -> dict:
               f"encode~{cells[name]['encode_s_extrapolated']:.1f}s")
     print(f"  (gemma merge slab capped at P={GEMMA_MERGE_P}; "
           f"codec measured on {shards}/{n_shards_total} shards)")
-    return {"cells": cells, "merge_p": GEMMA_MERGE_P,
-            "merge_wall_s": _bench_merge(COHORT, GEMMA_MERGE_P)}
+    return {"cells": cells, "merge_p": GEMMA_MERGE_P}
 
 
 def main() -> None:
@@ -274,18 +285,33 @@ def main() -> None:
     ap.add_argument("--gemma-shards", type=int, default=4)
     ap.add_argument("--merge-worker", nargs=2, type=int, metavar=("K", "P"),
                     help=argparse.SUPPRESS)
+    ap.add_argument("--cells-worker", choices=("small", "gemma"),
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.merge_worker:
         _merge_worker(*args.merge_worker)
         return
+    if args.cells_worker == "small":
+        TRACES.mkdir(parents=True, exist_ok=True)
+        print(json.dumps(_small_cells(args.rounds, args.seed, TRACES)))
+        return
+    if args.cells_worker == "gemma":
+        print(json.dumps(_gemma_cells(args.seed, args.gemma_shards)))
+        return
 
-    import tempfile
     grid: dict = {"mesh_sizes": list(MESH_SIZES)}
     if args.model in ("small", "both"):
-        with tempfile.TemporaryDirectory() as d:
-            grid["small_cnn"] = _small_cells(args.rounds, args.seed, Path(d))
+        small = _run_worker("--cells-worker", "small",
+                            "--rounds", str(args.rounds),
+                            "--seed", str(args.seed))
+        small["merge_wall_s"] = _bench_merge(COHORT, small["param_count"])
+        grid["small_cnn"] = small
     if args.model in ("gemma", "both"):
-        grid["gemma3-1b"] = _gemma_cells(args.seed, args.gemma_shards)
+        gemma = _run_worker("--cells-worker", "gemma",
+                            "--seed", str(args.seed),
+                            "--gemma-shards", str(args.gemma_shards))
+        gemma["merge_wall_s"] = _bench_merge(COHORT, GEMMA_MERGE_P)
+        grid["gemma3-1b"] = gemma
 
     OUT.parent.mkdir(parents=True, exist_ok=True)
     OUT.write_text(json.dumps(grid, indent=1))

@@ -25,7 +25,9 @@ element handoff cost is flat in P, same slab convention as
 ``bench_compression``); they are tier-2: run with ``--model gemma``
 (CI runs ``--model small`` only).
 
-Results land in ``results/BENCH_round_pipeline.json``.
+Results land in ``results/BENCH_round_pipeline.json``.  The parent
+process never touches JAX (an accelerator belongs to one process at a
+time): every measurement runs in a worker subprocess, one at a time.
 
 Run: ``PYTHONPATH=src python -m benchmarks.bench_round_pipeline``
 """
@@ -34,6 +36,8 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import subprocess
+import sys
 import time
 from pathlib import Path
 
@@ -141,6 +145,19 @@ def _handoff_grid(model: str) -> list:
     return cells
 
 
+def _run_worker(*argv, env=None):
+    """Run one measurement in a fresh worker process; echo its progress
+    lines and return the JSON record on its last line."""
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmarks.bench_round_pipeline", *argv],
+        capture_output=True, text=True, env=env or dict(os.environ),
+        check=True)
+    lines = proc.stdout.strip().splitlines()
+    for line in lines[:-1]:
+        print(line, flush=True)
+    return json.loads(lines[-1])
+
+
 # ----------------------------------------------------------------------
 # end-to-end small-CNN experiment, env gate toggled; each gate runs in
 # its own subprocess so neither inherits the other's in-process JIT
@@ -193,18 +210,11 @@ def _e2e_worker(rounds: int, seed: int) -> None:
 
 
 def _e2e_cell(rounds: int, seed: int) -> dict:
-    import subprocess
-    import sys
-
     out = {"rounds": rounds, "cohort": E2E_COHORT}
     for label, gate in (("pipeline", "1"), ("legacy", "0")):
         env = dict(os.environ)
         env["REPRO_DEVICE_PIPELINE"] = gate
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_round_pipeline",
-             "--e2e-worker", str(rounds), str(seed)],
-            capture_output=True, text=True, env=env, check=True)
-        rec = json.loads(proc.stdout.strip().splitlines()[-1])
+        rec = _run_worker("--e2e-worker", str(rounds), str(seed), env=env)
         out[label] = rec
         print(f"e2e/{label:8s} wall={rec['wall_s']:.2f}s "
               f"materialized={rec['materialize_bytes']} bytes "
@@ -227,18 +237,25 @@ def main() -> None:
                     default="small")
     ap.add_argument("--e2e-worker", nargs=2, type=int,
                     metavar=("ROUNDS", "SEED"), help=argparse.SUPPRESS)
+    ap.add_argument("--handoff-worker", choices=("small", "gemma"),
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.e2e_worker:
         _e2e_worker(*args.e2e_worker)
         return
+    if args.handoff_worker:
+        print(json.dumps(_handoff_grid(args.handoff_worker)))
+        return
 
     grid: dict = {"cohorts": list(COHORTS)}
     if args.model in ("small", "both"):
-        grid["small_cnn"] = {"handoff": _handoff_grid("small"),
+        grid["small_cnn"] = {"handoff": _run_worker("--handoff-worker",
+                                                    "small"),
                              "e2e": _e2e_cell(args.rounds, args.seed)}
     if args.model in ("gemma", "both"):
         grid["gemma3-1b_shard"] = {"shard_p": GEMMA_P,
-                                   "handoff": _handoff_grid("gemma")}
+                                   "handoff": _run_worker(
+                                       "--handoff-worker", "gemma")}
 
     OUT.parent.mkdir(parents=True, exist_ok=True)
     OUT.write_text(json.dumps(grid, indent=1))

@@ -62,7 +62,8 @@ GATES: Dict[str, Gate] = {g.name: g for g in (
     Gate(PALLAS_INTERPRET, None,
          "Pallas interpret-mode override: 1 forces the interpreter, 0 "
          "forces Mosaic lowering; unset picks interpret on CPU and "
-         "Mosaic on TPU (kernels/ops.py, read once at import)."),
+         "Mosaic on TPU.  Read at every kernel call "
+         "(kernels/ops.resolve_interpret); 1 on a TPU backend raises."),
 )}
 
 

@@ -305,8 +305,7 @@ _COLLECTIVE_CALLS = {
     "jax.lax.axis_index", "lax.axis_index", "axis_index",
 }
 
-_SHARD_MAP_CALLS = {"shard_map", "jax.experimental.shard_map.shard_map",
-                    "shd.shard_map"}
+_SHARD_MAP_CALLS = {"shard_map", "jax.shard_map"}
 
 
 class UndeclaredMeshAxisRule(Rule):

@@ -16,8 +16,9 @@ Updates are JAX pytrees.  `aggregate` has two paths:
     through the Pallas interpreter, which validates the kernel but is
     slower than the reference path), then unravelled back to the
     original tree structure;
-  * the per-leaf `tree_map` reference path, kept for validation and as
-    the fallback for exotic pytrees.
+  * the per-leaf `tree_map` reference path, kept for validation
+    (``REPRO_AGG_KERNEL=0`` or ``use_kernel=False``).  There is no
+    silent fallback: a kernel lowering or compile error propagates.
 """
 from __future__ import annotations
 
@@ -32,8 +33,6 @@ from jax.flatten_util import ravel_pytree
 from ..analysis import gates
 
 Pytree = Any
-
-_KERNEL_WARNED = False
 
 
 class ClientUpdate:
@@ -229,16 +228,7 @@ def aggregate(updates: Sequence[ClientUpdate], coeffs: np.ndarray,
         # per-test env flip reaches this default like every other gate
         use_kernel = gates.agg_kernel_enabled()
     if use_kernel:
-        try:
-            return _aggregate_flat(updates, coeffs, mesh=mesh)
-        except (TypeError, ValueError) as e:
-            # exotic pytrees that ravel_pytree/stack can't flatten
-            global _KERNEL_WARNED
-            if not _KERNEL_WARNED:
-                _KERNEL_WARNED = True
-                import warnings
-                warnings.warn(f"fed_agg kernel path fell back to the "
-                              f"tree_map reference path: {e}")
+        return _aggregate_flat(updates, coeffs, mesh=mesh)
     return aggregate_reference(updates, coeffs)
 
 

@@ -163,15 +163,10 @@ class MergePipeline:
     # ---- optimizer path ----------------------------------------------
     def _merge_opt(self, global_params, updates: List[ClientUpdate],
                    coeffs: np.ndarray, mix: float) -> Pytree:
+        # no fallback: a kernel lowering or compile error propagates, so
+        # a run never drops to the tree_map twin without saying so
         if self._kernel_enabled():
-            try:
-                return self._apply_kernel(global_params, updates, coeffs,
-                                          mix)
-            except (TypeError, ValueError) as e:
-                # exotic pytrees that ravel_pytree/stack can't flatten
-                import warnings
-                warnings.warn(f"fed_agg_apply kernel path fell back to "
-                              f"the tree_map reference path: {e}")
+            return self._apply_kernel(global_params, updates, coeffs, mix)
         return self._apply_tree(global_params, updates, coeffs, mix)
 
     def _kernel_scalars(self):
@@ -188,8 +183,6 @@ class MergePipeline:
         # rows straight out of the executor's (K, P) matrix
         mat, _ = flat_update_matrix(updates)
         if mat.shape[1] != flat_g.shape[0]:
-            # a genuine layout error, not an exotic-pytree condition —
-            # RuntimeError so the fallback handler doesn't mislabel it
             raise RuntimeError(
                 f"update/global size mismatch: updates ravel to "
                 f"{mat.shape[1]} parameters, global model to "
@@ -230,8 +223,8 @@ class MergePipeline:
         return unravel(out.astype(flat_g.dtype))
 
     def _apply_tree(self, global_params, updates, coeffs, mix):
-        """Per-leaf `tree_map` twin of the fused kernel (validation path,
-        and the fallback for pytrees the flattened layout can't take)."""
+        """Per-leaf `tree_map` twin of the fused kernel (the validation
+        path, selected by ``REPRO_AGG_KERNEL=0`` or ``use_kernel=False``)."""
         c = self.config
         tm = jax.tree_util.tree_map
         avg = aggregate_reference(updates, coeffs)

@@ -94,6 +94,8 @@ class ExperimentResult:
     # cost attribution (CostMeter breakdown), populated by run()
     cost_by_client: Dict[str, float] = field(default_factory=dict)
     cost_by_round: Dict[int, float] = field(default_factory=dict)
+    # the trained global model (set by fl.experiment.run_experiment)
+    final_params: Any = field(default=None, repr=False)
 
     @property
     def total_duration_s(self) -> float:

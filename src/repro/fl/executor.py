@@ -61,7 +61,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -210,12 +209,12 @@ class VectorizedExecutor:
             # split the cohort (K) dim over the 'clients' axis: each
             # device vmaps its own slice, Adam states included (built by
             # optimizer.init inside the mapped body, so they never exist
-            # unsharded); global params replicate.  check_rep=False —
-            # the replicated-input analysis chokes on the scan carry.
+            # unsharded); global params replicate.  check_vma=False —
+            # the varying-axes analysis chokes on the scan carry.
             spec = cohort_spec()
-            cohort = shard_map(cohort, mesh=self.mesh,
-                               in_specs=(P(), spec, spec, spec),
-                               out_specs=(spec, spec), check_rep=False)
+            cohort = jax.shard_map(cohort, mesh=self.mesh,
+                                   in_specs=(P(), spec, spec, spec),
+                                   out_specs=(spec, spec), check_vma=False)
         # memoized per (mu, mesh) in _jit_cache (guard at the top), so
         # construction happens once per setting, not per round
         fn = jax.jit(cohort)  # repro-lint: disable=JAX003
@@ -283,6 +282,11 @@ class VectorizedExecutor:
             self._dispatch_keys.add(key)
             self._compile_counts[mesh_key] = \
                 self._compile_counts.get(mesh_key, 0) + 1
+        if self.mesh is not None:
+            # replicate over the mesh: an unsharded merge leaves the
+            # params committed to one device, which the dispatch refuses
+            global_params = jax.device_put(global_params,
+                                           NamedSharding(self.mesh, P()))
         return self._group_fn(mu)(
             global_params, self._place(xs), self._place(ys), self._place(ms))
 

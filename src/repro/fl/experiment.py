@@ -124,10 +124,11 @@ class ExperimentConfig:
     dispatch_timing: bool = False
     # round-pipeline compilation surface (launch/compile_cache.py):
     # a directory enables JAX's persistent compilation cache, so repeat
-    # runs (and CI) skip XLA compiles entirely; executor_warmup runs one
-    # throwaway vectorized dispatch before round 0 so compilation never
-    # lands inside the timed loop (off by default — warm-up itself costs
-    # one cohort's training compute)
+    # runs (and CI) skip XLA compiles entirely — ignored when
+    # JAX_COMPILATION_CACHE_DIR is set, which always wins; executor_warmup
+    # runs one throwaway vectorized dispatch before round 0 so compilation
+    # never lands inside the timed loop (off by default — warm-up itself
+    # costs one cohort's training compute)
     compilation_cache_dir: Optional[str] = None
     executor_warmup: bool = False
 
@@ -265,10 +266,13 @@ def run_experiment(task: ClassificationTask,
 
     if config.executor_warmup:
         controller.warmup_executor(params)
-    _, result = controller.run(params, config.n_rounds, verbose=verbose,
-                               start_round=start_round,
-                               checkpointer=checkpointer,
-                               checkpoint_every=config.checkpoint_every)
+    final_params, result = controller.run(params, config.n_rounds,
+                                          verbose=verbose,
+                                          start_round=start_round,
+                                          checkpointer=checkpointer,
+                                          checkpoint_every=(
+                                              config.checkpoint_every))
+    result.final_params = final_params
     if recorder is not None:
         recorder.to_jsonl(config.trace_path)
     return result

@@ -65,17 +65,19 @@ class TrailingMetricsCache:
 
     def __init__(self, window: int = 3):
         self.window = window
+        # the keyed window itself, not its ids: holding the objects keeps
+        # a freed window's ids from being reused by new stats objects,
+        # which would replay a stale value
         self._key: tuple = ()
         self._value = (1.0, 0.0)
 
     def compute(self, stats: Sequence) -> tuple:
         """(trailing_eur, trailing_straggler_ratio) over `stats`."""
-        recent = list(stats)[-self.window:]
-        key = tuple(map(id, recent))
-        if key != self._key or not key:
+        recent = tuple(stats)[-self.window:]
+        if not recent or tuple(map(id, recent)) != tuple(map(id, self._key)):
             self._value = (trailing_eur(recent, self.window),
                            trailing_straggler_ratio(recent, self.window))
-            self._key = key
+            self._key = recent
         return self._value
 
 

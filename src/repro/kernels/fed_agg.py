@@ -30,7 +30,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..sharding.rules import merge_axes
@@ -38,6 +37,8 @@ from ..sharding.rules import merge_axes
 # optimizer families the fused apply kernel can lower; "sgd"/"fedavgm"
 # share the heavy-ball branch (momentum 0 reduces to plain server-SGD)
 APPLY_OPTS = ("sgd", "fedavgm", "fedadagrad", "fedadam", "fedyogi")
+# TPU vector lane width: the narrowest output block Mosaic accepts
+_LANES = 128
 
 
 def _fed_agg_kernel(coeff_ref, upd_ref, out_ref):
@@ -135,7 +136,10 @@ def _make_apply_kernel(opt: str):
         g = g_ref[...].astype(jnp.float32)              # (1, TP)
         s = jnp.sum(upd * coeff, axis=0, keepdims=True)
         delta = mix * (s - g)
-        sq_ref[0, 0] = jnp.sum(delta * delta)
+        # one lane-aligned (1, 128) block per tile (Mosaic refuses (1, 1)
+        # blocks); every lane holds the tile's Σ Δ², lane 0 is read back
+        sq_ref[...] = jnp.full(sq_ref.shape, jnp.sum(delta * delta),
+                               jnp.float32)
         if opt in ("sgd", "fedavgm"):
             # heavy-ball: m ← β·m + Δ (β = server momentum; 0 → plain Δ)
             m = b1 * m_ref[...] + delta
@@ -199,12 +203,12 @@ def _fed_agg_apply_impl(updates: jnp.ndarray, coeffs: jnp.ndarray,
             vec_spec, vec_spec, vec_spec,
         ],
         out_specs=[vec_spec, vec_spec, vec_spec,
-                   pl.BlockSpec((1, 1), lambda i: (0, i))],
+                   pl.BlockSpec((1, _LANES), lambda i: (0, i))],
         out_shape=[vec, vec, vec,
-                   jax.ShapeDtypeStruct((1, n_tiles), jnp.float32)],
+                   jax.ShapeDtypeStruct((1, n_tiles * _LANES), jnp.float32)],
         interpret=interpret,
     )(scal, coeffs2, updates, g2, m2, v2)
-    norm = jnp.sqrt(jnp.sum(sq))
+    norm = jnp.sqrt(jnp.sum(sq.reshape(n_tiles, _LANES)[:, 0]))
     return out[0, :P], m_new[0, :P], v_new[0, :P], norm
 
 
@@ -264,11 +268,11 @@ def fed_agg_sharded(updates: jnp.ndarray, coeffs: jnp.ndarray, mesh,
     Pdim = updates.shape[1]
     upd = _pad_p(updates, n)
 
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(fed_agg, tile_p=tile_p, interpret=interpret),
         mesh=mesh,
         in_specs=(P(None, axes), P(None)),
-        out_specs=P(axes), check_rep=False)
+        out_specs=P(axes), check_vma=False)
     return f(upd, coeffs)[:Pdim]
 
 
@@ -303,8 +307,8 @@ def fed_agg_apply_sharded(updates: jnp.ndarray, coeffs: jnp.ndarray,
         return out, m_new, v_new, jnp.sqrt(sumsq)
 
     vec = P(axes)
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(P(None, axes), P(None), vec, vec, vec),
-                  out_specs=(vec, vec, vec, P()), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(None, axes), P(None), vec, vec, vec),
+                      out_specs=(vec, vec, vec, P()), check_vma=False)
     out, m_new, v_new, norm = f(upd, coeffs, g2, m2, v2)
     return out[:Pdim], m_new[:Pdim], v_new[:Pdim], norm

@@ -21,6 +21,7 @@ from ..data.synthetic import ArrayDataset
 from ..fl.experiment import (ExperimentConfig, ScenarioConfig,
                              run_experiment)
 from ..fl.tasks import ClassificationTask, TaskConfig
+from ..launch.compile_cache import enable_compilation_cache
 from ..models.small import make_char_lstm, make_cnn, make_speech_cnn
 
 
@@ -77,6 +78,8 @@ def main() -> None:
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args()
 
+    # $JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache
+    enable_compilation_cache()
     task, parts, test_parts = build_dataset(args.dataset, args.clients,
                                             args.seed)
     cfg = ExperimentConfig(

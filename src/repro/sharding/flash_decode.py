@@ -18,7 +18,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG = -1e30
@@ -67,10 +66,10 @@ def sharded_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     cspec = P(baxis, None, seq_axis, None)
     pspec = P(baxis)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(qspec, cspec, cspec, pspec),
              out_specs=P(baxis, None, None, None),
-             check_rep=False)
+             check_vma=False)
     def body(qg, k, v, p_):
         shard = jax.lax.axis_index(seq_axis)
         base = shard * s_loc

@@ -1,14 +1,13 @@
 """JAX004 fixture: shard_map / psum axis names that no sharding/rules.py
 declares (the corpus has no such module, so the vocabulary is empty)."""
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
 def sharded_sum(mesh, x):
-    f = shard_map(lambda a: a.sum(), mesh=mesh,
-                  in_specs=(P("cohort"),),
-                  out_specs=P())
+    f = jax.shard_map(lambda a: a.sum(), mesh=mesh,
+                      in_specs=(P("cohort"),),
+                      out_specs=P())
     return f(x)
 
 

@@ -37,8 +37,8 @@ EXPECTED = {
     ("JAX002", "core/bad_use_after_donate.py", 11),
     ("JAX002", "core/bad_use_after_donate.py", 16),
     ("JAX003", "fl/bad_jit_in_round.py", 8),
-    ("JAX004", "kernels/bad_shard_axes.py", 10),
-    ("JAX004", "kernels/bad_shard_axes.py", 16),
+    ("JAX004", "kernels/bad_shard_axes.py", 9),
+    ("JAX004", "kernels/bad_shard_axes.py", 15),
     ("GATE001", "core/bad_env_gate.py", 4),
     ("GATE001", "core/bad_env_gate.py", 5),
     ("CON001", "kernels/__init__.py", 5),

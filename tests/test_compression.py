@@ -46,6 +46,8 @@ from repro.fl.tasks import ClassificationTask, TaskConfig
 from repro.kernels import ops
 from repro.kernels.ref import int8_decode_ref, int8_encode_ref, topk_ref
 
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
+
 
 # ---------------------------------------------------------------- kernels
 def test_int8_roundtrip_exact_on_representable_grid():
@@ -398,7 +400,7 @@ MULTI_DEVICE_SCRIPT = textwrap.dedent("""
 def test_sharded_merge_two_device_subprocess():
     res = subprocess.run([sys.executable, "-c", MULTI_DEVICE_SCRIPT],
                          capture_output=True, text=True, timeout=600,
-                         cwd="/root/repo")
+                         cwd=str(REPO_ROOT))
     assert "SHARDED-OK" in res.stdout, res.stdout + res.stderr
 
 

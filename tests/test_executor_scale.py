@@ -357,6 +357,24 @@ MULTI_DEVICE_SCRIPT = textwrap.dedent("""
     ex.run_group(odd, [pool.clients[c].dataset for c in odd], params, 0.0,
                  [pool.client_seed(c, 0) for c in odd])
 
+    # ---- an unsharded merge kernel gets the row-sharded matrix on one
+    # device: Mosaic refuses a kernel spread over a mesh outside shard_map
+    from repro.kernels import ops
+    mat = ex.run_group_batch(cids, datasets, params, 0.0, seeds).mat
+    assert len(mat.sharding.device_set) == 2
+    cf = jnp.full((mat.shape[0],), 0.25)
+    seen, real = [], ops._fed_agg
+    def spy(u, c, **kw):
+        seen.append(len(u.sharding.device_set))
+        return real(u, c, **kw)
+    ops._fed_agg = spy
+    got = ops.fed_agg(mat, cf)
+    ops._fed_agg = real
+    assert seen == [1], seen
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(ops.fed_agg(jnp.asarray(np.asarray(mat)),
+                                                cf)))
+
     # ---- driver-level parity across all three modes ------------------
     import hashlib
     from repro.core import ClientHistoryDB, StrategyConfig, make_strategy

@@ -7,6 +7,7 @@ devices (the device count must be set before jax initialises).
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +15,8 @@ import numpy as np
 from repro.launch.mesh import make_host_mesh
 from repro.sharding.flash_decode import (reference_decode_attention,
                                          sharded_decode_attention)
+
+REPO_ROOT = Path(__file__).resolve().parents[1]
 
 
 def test_single_shard_matches_oracle():
@@ -72,5 +75,5 @@ MULTI_DEVICE_SCRIPT = textwrap.dedent("""
 def test_multi_shard_combine_subprocess():
     res = subprocess.run([sys.executable, "-c", MULTI_DEVICE_SCRIPT],
                          capture_output=True, text=True, timeout=600,
-                         cwd="/root/repo")
+                         cwd=str(REPO_ROOT))
     assert "MULTI-OK" in res.stdout, res.stdout + res.stderr

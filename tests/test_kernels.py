@@ -190,3 +190,35 @@ def test_ssd_state_continuity_vs_model_path():
     y1 = ssd_scan(x, a, B, C, chunk=32)
     y2, _ = ssd_chunked(x, a, B, C, chunk=64)
     np.testing.assert_allclose(y1, y2, rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------------------ interpret mode
+def test_interpret_mode_resolved_per_call(monkeypatch):
+    """The interpret flag is read at every call (no import-time latch):
+    explicit arg, else REPRO_PALLAS_INTERPRET, else the backend."""
+    from repro.analysis import gates
+    from repro.kernels.ops import resolve_interpret
+
+    monkeypatch.delenv(gates.PALLAS_INTERPRET, raising=False)
+    assert resolve_interpret() is (jax.default_backend() == "cpu")
+    monkeypatch.setenv(gates.PALLAS_INTERPRET, "0")
+    assert resolve_interpret() is False
+    monkeypatch.setenv(gates.PALLAS_INTERPRET, "1")
+    assert resolve_interpret() is True
+    assert resolve_interpret(False) is False
+
+
+def test_forced_interpret_on_tpu_backend_raises(monkeypatch):
+    """On a TPU backend the kernels lower to Mosaic; forcing the
+    interpreter there raises instead of silently bypassing the chip."""
+    from repro.analysis import gates
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv(gates.PALLAS_INTERPRET, raising=False)
+    assert ops.resolve_interpret() is False
+    with pytest.raises(RuntimeError, match="interpret"):
+        ops.resolve_interpret(True)
+    monkeypatch.setenv(gates.PALLAS_INTERPRET, "1")
+    with pytest.raises(RuntimeError, match="interpret"):
+        ops.fed_agg(jnp.ones((2, 8)), jnp.ones(2))

@@ -1,7 +1,9 @@
 """Integration: the pjit pretraining driver trains a reduced assigned
 arch end to end (sharded init → jit train steps → checkpoint restore)."""
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +15,8 @@ from repro.data.synthetic import make_token_lm
 from repro.launch.mesh import make_host_mesh
 from repro.models import make_train_step
 from repro.sharding import opt_specs, param_specs, to_named
+
+REPO_ROOT = Path(__file__).resolve().parents[1]
 
 
 def test_pretrain_loss_decreases(tmp_path):
@@ -54,8 +58,7 @@ def test_pretrain_cli_smoke():
            "--arch", "gemma2-2b", "--steps", "6", "--batch", "4",
            "--seq", "32", "--log-every", "3"]
     res = subprocess.run(cmd, capture_output=True, text=True, timeout=600,
-                         cwd="/root/repo",
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                              "HOME": "/root"})
+                         cwd=str(REPO_ROOT),
+                         env={**os.environ, "PYTHONPATH": "src"})
     assert res.returncode == 0, res.stderr[-2000:]
     assert "final: loss" in res.stdout

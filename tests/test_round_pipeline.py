@@ -12,6 +12,11 @@ losses sync host-side in one batched transfer, and the recompile counter
 stays flat across rounds whose cohorts share a power-of-two bucket.
 """
 import hashlib
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -365,3 +370,43 @@ def test_client_update_batch_semantics():
         batch.row(2)                          # padding rows unaddressable
     assert batch.loss(1) == 0.25
     assert pipeline_enabled() in (True, False)
+
+
+# ------------------------------------------------- compile-cache placement
+REPO_ROOT = Path(__file__).resolve().parents[1]
+CACHE_SCRIPT = textwrap.dedent("""
+    import sys
+    import jax, jax.numpy as jnp
+    from repro.launch import compile_cache
+    compile_cache.DEFAULT_CACHE_DIR = sys.argv[1]
+    print(compile_cache.enable_compilation_cache(sys.argv[2] or None))
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.arange(5.0)).block_until_ready()
+""")
+
+
+def test_compile_cache_default_is_in_checkout():
+    from repro.launch.compile_cache import DEFAULT_CACHE_DIR
+    assert Path(DEFAULT_CACHE_DIR) == REPO_ROOT / ".jax_cache"
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_entries_land_in_one_place(tmp_path, env_set):
+    """JAX_COMPILATION_CACHE_DIR, when set, wins over any path given in
+    code; otherwise the entry point's default directory is used."""
+    default, given, from_env = (tmp_path / "default", tmp_path / "given",
+                                tmp_path / "env")
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(from_env)
+    res = subprocess.run(
+        [sys.executable, "-c", CACHE_SCRIPT, str(default),
+         str(given) if env_set else ""],
+        capture_output=True, text=True, timeout=300, cwd=str(REPO_ROOT),
+        env=env)
+    assert res.returncode == 0, res.stderr[-2000:]
+    want = from_env if env_set else default
+    assert res.stdout.strip().splitlines()[-1] == str(want)
+    assert any(want.iterdir())
+    others = [d for d in (default, given, from_env) if d != want]
+    assert not any(d.exists() for d in others)

@@ -165,6 +165,30 @@ def test_adaptive_cohort_grows_and_shrinks_with_eur():
     assert sched._size >= sched.min_cohort
 
 
+def test_trailing_cache_holds_its_window():
+    """The trailing-metrics memo is keyed on object identity, so it must
+    hold its window: a freed stats object's id can otherwise be taken by
+    a new one, which then replays the stale value."""
+    import gc
+    import weakref
+
+    from repro.fl.metrics import TrailingMetricsCache
+
+    class Stats:                         # weak-referenceable RoundStats
+        def __init__(self, eur):
+            self.eur, self.selected = eur, ["x"] * 6
+            self.late, self.crashed = [], []
+
+    cache = TrailingMetricsCache(3)
+    s = Stats(1.0)
+    assert cache.compute([s] * 3) == (1.0, 0.0)
+    ref = weakref.ref(s)
+    del s
+    gc.collect()
+    assert ref() is not None
+    assert cache.compute([Stats(0.3)] * 3)[0] == pytest.approx(0.3)
+
+
 def test_adaptive_delegates_selection_to_inner():
     inner = RandomScheduler(6, seed=4)
     sched = AdaptiveScheduler(6, inner=inner)

@@ -491,3 +491,23 @@ def test_experiment_surface_threads_server_opt(tmp_path):
     from repro.faas.trace import load_jsonl
     aggs = [r for r in load_jsonl(trace_path) if r["type"] == "aggregation"]
     assert aggs and all(a["server_opt"] == "fedadam" for a in aggs)
+
+
+def test_kernel_errors_propagate_without_fallback(monkeypatch):
+    """A kernel lowering or compile error surfaces to the caller: neither
+    the optimizer merge nor the identity weighted sum drops to the
+    tree_map twin behind its back."""
+    import repro.kernels as kernels
+
+    def refused(*args, **kwargs):
+        raise ValueError("Mosaic refused the block shape")
+
+    monkeypatch.setattr(kernels, "fed_agg_apply", refused)
+    monkeypatch.setattr(kernels, "fed_agg", refused)
+    g = {"w": jnp.zeros(4)}
+    ups = [ClientUpdate("a", {"w": jnp.ones(4)}, 1, 0)]
+    with pytest.raises(ValueError, match="Mosaic"):
+        MergePipeline(ServerOptConfig(name="fedadam"),
+                      use_kernel=True).merge(g, ups, [1.0])
+    with pytest.raises(ValueError, match="Mosaic"):
+        aggregate(ups, np.array([1.0]), use_kernel=True)
