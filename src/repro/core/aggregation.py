@@ -57,14 +57,13 @@ class ClientUpdate:
 
     __slots__ = ("client_id", "num_samples", "round_number",
                  "training_time", "payload_bytes", "dense_bytes",
-                 "dispatch_s", "batch", "batch_row", "_params")
+                 "batch", "batch_row", "_params")
 
     def __init__(self, client_id: str, params: Pytree = None,
                  num_samples: int = 0, round_number: int = 0,
                  training_time: float = 0.0,
                  payload_bytes: Optional[int] = None,
                  dense_bytes: Optional[int] = None,
-                 dispatch_s: Optional[float] = None,
                  batch=None, batch_row: int = -1):
         self.client_id = client_id
         self._params = params
@@ -73,9 +72,6 @@ class ClientUpdate:
         self.training_time = training_time
         self.payload_bytes = payload_bytes  # encoded wire size (simulated)
         self.dense_bytes = dense_bytes      # uncompressed fp32 wire size
-        # wall-clock executor launch latency (telemetry; None unless the
-        # executor's timing collection is on — never enters virtual time)
-        self.dispatch_s = dispatch_s
         self.batch = batch                  # DeviceUpdateBatch, or None
         self.batch_row = batch_row
         if params is None and batch is None:
@@ -119,19 +115,18 @@ def update_to_record(update: ClientUpdate) -> dict:
     if update.payload_bytes is not None:
         rec["payload_bytes"] = update.payload_bytes
         rec["dense_bytes"] = update.dense_bytes
-    if update.dispatch_s is not None:
-        rec["dispatch_s"] = update.dispatch_s
     return rec
 
 
 def update_from_record(rec: dict, params: Pytree) -> ClientUpdate:
+    """Inverse of `update_to_record`; keys it does not write (such as
+    an older build's ``dispatch_s``) are ignored."""
     return ClientUpdate(params=params, client_id=rec["client_id"],
                         num_samples=rec["num_samples"],
                         round_number=rec["round_number"],
                         training_time=rec.get("training_time", 0.0),
                         payload_bytes=rec.get("payload_bytes"),
-                        dense_bytes=rec.get("dense_bytes"),
-                        dispatch_s=rec.get("dispatch_s"))
+                        dense_bytes=rec.get("dense_bytes"))
 
 
 @partial(jax.jit, static_argnums=())

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis import gates
+from . import spans
 
 Pytree = Any
 
@@ -42,32 +43,23 @@ def pipeline_enabled() -> bool:
     return gates.device_pipeline_enabled()
 
 
-# ----------------------------------------------------------------------
-# host-transfer accounting — the benchmark's churn metric.  Counts bytes
-# that cross the executor→merge boundary as *per-client* materializations
-# (row unravels / full-tree rebuilds); the device pipeline's claim is
-# that the dense path drops from 2·K·model-size to ≤ 1·model-size.
-# ----------------------------------------------------------------------
-_TRANSFER = {"materialize_bytes": 0, "materialize_rows": 0,
-             "loss_syncs": 0}
-
-
+# host-transfer accounting — the counters live in core/spans.py with
+# the round's other counters.  Counts bytes that cross the
+# executor→merge boundary as *per-client* materializations (row
+# unravels / full-tree rebuilds); the device pipeline's claim is that
+# the dense path drops from 2·K·model-size to ≤ 1·model-size.
 def transfer_stats() -> Dict[str, int]:
-    return dict(_TRANSFER)
+    """Every round counter (core/spans.py), the transfer ones included."""
+    return spans.counters()
 
 
 def reset_transfer_stats() -> None:
-    for k in _TRANSFER:
-        _TRANSFER[k] = 0
+    spans.reset_counters()
 
 
 def count_materialization(nbytes: int, rows: int = 1) -> None:
-    _TRANSFER["materialize_bytes"] += int(nbytes)
-    _TRANSFER["materialize_rows"] += int(rows)
-
-
-def count_loss_sync() -> None:
-    _TRANSFER["loss_syncs"] += 1
+    spans.count("materialize_bytes", nbytes)
+    spans.count("materialize_rows", rows)
 
 
 class DeviceUpdateBatch:
@@ -159,6 +151,7 @@ class DeviceUpdateBatch:
         if self._losses is None:
             return 0.0
         if self._losses_np is None:
-            self._losses_np = np.asarray(self._losses)
-            count_loss_sync()
+            with spans.sync("loss", self._losses.nbytes):
+                self._losses_np = np.asarray(self._losses)
+            spans.count("loss_syncs")
         return float(self._losses_np[i])

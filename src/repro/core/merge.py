@@ -49,6 +49,7 @@ from jax.flatten_util import ravel_pytree
 
 from ..analysis import gates
 from ..optim.optimizers import global_norm, zeros_like_f32
+from . import spans
 from .aggregation import (ClientUpdate, aggregate, aggregate_reference,
                           flat_update_matrix)
 
@@ -217,7 +218,8 @@ class MergePipeline:
         self._m = self._unravel32(m_new)
         if self.config.name in _ADAPTIVE:
             self._v = self._unravel32(v_new)
-        self.last_update_norm = float(norm)
+        with spans.sync("merge_norm", norm.nbytes):
+            self.last_update_norm = float(norm)
         # cast to the *promoted* flat dtype; unravel itself restores each
         # leaf's own dtype (mixed-precision trees keep full precision)
         return unravel(out.astype(flat_g.dtype))
@@ -252,7 +254,9 @@ class MergePipeline:
                     * jnp.sign(v - d * d), self._v, delta)
             step = tm(lambda m, v: m / (jnp.sqrt(v) + c.eps),
                       self._m, self._v)
-        self.last_update_norm = float(global_norm(delta))
+        norm = global_norm(delta)
+        with spans.sync("merge_norm", norm.nbytes):
+            self.last_update_norm = float(norm)
         return tm(lambda g, s: (g.astype(jnp.float32)
                                 + c.lr * s).astype(g.dtype),
                   global_params, step)

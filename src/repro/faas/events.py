@@ -39,6 +39,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from ..core import spans
 from .platform import VirtualClock
 
 
@@ -151,6 +152,7 @@ class EventQueue:
             if ev.cancelled:
                 continue
             self._live -= 1
+            spans.count("events")
             # detach: a later cancel() of this already-delivered event
             # (fired deadlines, resolved lifecycles) must not decrement
             # the live counter a second time

@@ -154,11 +154,6 @@ class InvocationEngine:
         cached = st.work.get(cid)
         payload = (cached[0].payload_bytes
                    if cached is not None and cached[0] is not None else None)
-        # dispatch_s is wall-clock launch telemetry stamped by the
-        # executor when timing collection is on — like payload_bytes it
-        # is only-when-set, so dense/default traces stay byte-identical
-        dispatch = (cached[0].dispatch_s
-                    if cached is not None and cached[0] is not None else None)
         # the platform captured at _start time: platform_of() may be a
         # *mutating* routing call (TelemetryRoutingPolicy can re-route),
         # so it must not be re-resolved as a side effect of logging
@@ -168,7 +163,7 @@ class InvocationEngine:
             start_time=plan.start_time, arrival_time=arrival_time,
             cold=plan.cold, cold_start_s=plan.cold_start_s,
             billed_s=outcome.duration_s, status=status,
-            payload_bytes=payload, dispatch_s=dispatch)
+            payload_bytes=payload)
 
     # ------------------------------------------------------------------
     def open_round(self, queue: EventQueue, client_ids: Sequence[str],

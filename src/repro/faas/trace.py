@@ -64,7 +64,7 @@ RECORD_SCHEMAS = {
         "required": ["client_id", "platform", "round", "attempt",
                      "start_time", "arrival_time", "cold",
                      "cold_start_s", "billed_s", "status"],
-        "optional": ["payload_bytes", "dispatch_s", "ticket"],
+        "optional": ["payload_bytes", "ticket"],
         "open": False,
     },
     REC_BILLING: {
@@ -157,20 +157,16 @@ class TraceRecorder:
     def attempt(self, *, client_id: str, platform: str, round_number,
                 attempt: int, start_time: float, arrival_time: float,
                 cold: bool, cold_start_s: float, billed_s: float,
-                status: str, payload_bytes: Optional[int] = None,
-                dispatch_s: Optional[float] = None) -> None:
+                status: str, payload_bytes: Optional[int] = None) -> None:
         """One resolved invocation attempt (success, failure, or a crash
         discovered at a deadline).  `status` is "ok" or a failure reason
         from faas.platform (crash/platform/timeout).  `payload_bytes` is
         the update's simulated wire size when compression is on — None
         (the dense default) keeps the record's key set byte-identical to
-        pre-compression traces.  `dispatch_s` is the executor's wall-clock
-        group-dispatch latency when timing collection is on — same
-        only-when-set rule, so default traces never gain the key.  Pure
-        record sink — telemetry windows are fed by `on_plan` (one
-        observation per sampled attempt), never here, so a recorder
-        attached to both the engine and the platforms counts each attempt
-        once."""
+        pre-compression traces.  Pure record sink — telemetry windows
+        are fed by `on_plan` (one observation per sampled attempt), never
+        here, so a recorder attached to both the engine and the
+        platforms counts each attempt once."""
         rec = {
             "type": REC_ATTEMPT, "client_id": client_id,
             "platform": platform, "round": round_number,
@@ -181,8 +177,6 @@ class TraceRecorder:
         }
         if payload_bytes is not None:
             rec["payload_bytes"] = payload_bytes
-        if dispatch_s is not None:
-            rec["dispatch_s"] = dispatch_s
         if round_number in self._round_aliases:
             rec["ticket"] = round_number
             rec["round"] = self._round_aliases[round_number]

@@ -50,6 +50,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ..core import spans
 from ..core.history import ClientHistoryDB
 from ..core.strategies import Strategy
 from ..faas.cost import CostMeter
@@ -235,10 +236,12 @@ class TrainingDriver:
         k = max(1, int(len(ids) * self.eval_fraction))
         sample = self.rng.choice(ids, size=min(k, len(ids)), replace=False)
         per_client = []
-        for cid in sample:
-            ds = self.pool.clients[cid].test_dataset
-            acc, _ = self.pool.task.evaluate(params, ds)
-            per_client.append((acc, len(ds)))
+        with spans.span("fl.eval", clients=len(sample)) as ev:
+            for cid in sample:
+                ds = self.pool.clients[cid].test_dataset
+                acc, _ = self.pool.task.evaluate(params, ds)
+                per_client.append((acc, len(ds)))
+            ev.set_metadata(samples=sum(n for _, n in per_client))
         return weighted_accuracy(per_client)
 
     def _print_progress(self, label: str, stats: RoundStats) -> None:
@@ -359,19 +362,39 @@ class TrainingDriver:
 
     def run_round(self, global_params: Pytree,
                   round_number: int) -> tuple:
-        """One Train_Global_Model iteration. Returns (params, RoundStats)."""
+        """One Train_Global_Model iteration. Returns (params, RoundStats).
+
+        The ``fl.round`` span covers it; at its end the span gets the
+        round's deltas of the event, host-sync, staged-byte and compile
+        counters (core/spans.py)."""
         if self.mode == "async":
             raise RuntimeError("run_round is a barrier API; the async mode "
                                "runs barrier-free — use run()")
+        before = spans.counters()
+        with spans.span("fl.round", round=round_number) as span:
+            out = self._run_round(global_params, round_number)
+            after = spans.counters()
+            span.set_metadata(
+                selected=len(out[1].selected),
+                events=after["events"] - before["events"],
+                syncs=after["host_syncs"] - before["host_syncs"],
+                staged_bytes=after["staged_bytes"] - before["staged_bytes"],
+                compiles=after["compiles"] - before["compiles"])
+        return out
+
+    def _run_round(self, global_params: Pytree, round_number: int) -> tuple:
         clock = self.queue.clock
         t0 = clock.now
         deadline = t0 + self.round_timeout_s
 
         # the Scheduler owns the cohort decision: how many (adaptive
         # sizing over trailing RoundStats) and whom
-        want = self.scheduler.cohort_size(round_number, self._recent_stats)
-        selected = self.scheduler.propose(self.pool.client_ids, want, t0,
-                                          round_number)
+        with spans.span("fl.schedule", round=round_number) as sched:
+            want = self.scheduler.cohort_size(round_number,
+                                              self._recent_stats)
+            sched.set_metadata(want=want)
+            selected = self.scheduler.propose(self.pool.client_ids, want,
+                                              t0, round_number)
         self.strategy.last_plan = getattr(self.scheduler, "last_plan",
                                           self.strategy.last_plan)
         self._record_scheduling(t0, round_number, want, selected,
@@ -495,13 +518,14 @@ class TrainingDriver:
         # --- aggregation runs at round close (virtual now) --------------
         self.strategy.on_round_close(round_number, now=close_time)
         updates = [c.update for c in successes if c.update is not None]
-        if self._agg_takes_global:
-            new_params = self.strategy.aggregate(
-                updates, round_number, now=close_time,
-                global_params=global_params)
-        else:                       # legacy pre-pipeline override
-            new_params = self.strategy.aggregate(updates, round_number,
-                                                 now=close_time)
+        with spans.span("fl.merge", round=round_number, k=len(updates)):
+            if self._agg_takes_global:
+                new_params = self.strategy.aggregate(
+                    updates, round_number, now=close_time,
+                    global_params=global_params)
+            else:                       # legacy pre-pipeline override
+                new_params = self.strategy.aggregate(updates, round_number,
+                                                     now=close_time)
         if new_params is None:
             new_params = global_params
         # wire-size telemetry for the aggregation record: every update the

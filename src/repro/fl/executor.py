@@ -51,10 +51,16 @@ syncs left are the existing single batched loss fetch and the merge
 read-back.  ``0`` blocks right here until the trained stack is ready.
 Virtual time never reads the wall clock, so traces are byte-identical
 either way.
+
+Profiler spans (core/spans.py): ``fl.stage`` (host gather ``fl.gather``
+and host→device ``fl.put``), ``fl.dispatch`` (the enqueue of the
+group-train and flatten programs), ``fl.package`` and ``fl.sync`` on
+the loss fetch and the overlap-off block; inside the group-train
+program the local step's parts carry the named scopes ``loss_grad``,
+``proximal`` and ``optimizer``.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -65,6 +71,7 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..analysis import gates
+from ..core import spans
 from ..core.device_batch import DeviceUpdateBatch, pipeline_enabled
 from ..optim import apply_updates, proximal_grad
 from ..sharding.rules import cohort_spec
@@ -136,11 +143,6 @@ class VectorizedExecutor:
         # silently reusing a stale bucket.
         self._dispatch_keys: set = set()
         self._compile_counts: Dict[Any, int] = {}
-        # telemetry (wall-clock, never fed back into virtual time): when
-        # enabled, each group dispatch's launch latency is recorded and
-        # stamped onto the packaged ClientUpdates as ``dispatch_s``
-        self.collect_timing = False
-        self.last_dispatch_s: Optional[float] = None
 
     # ------------------------------------------------------------------
     def configure_mesh(self, mesh) -> None:
@@ -187,14 +189,22 @@ class VectorizedExecutor:
         def one_client(global_params, xs, ys, ms):
             opt_state = optimizer.init(global_params)
 
+            # the named scopes label the step's parts in the HLO
+            # metadata (and so in a device trace); the program is
+            # otherwise unchanged
             def step(carry, batch):
                 params, opt_state = carry
                 x, y, m = batch
-                loss, grads = jax.value_and_grad(masked_loss)(params, x, y, m)
-                grads = proximal_grad(grads, params, global_params, mu)
-                updates, opt_state = optimizer.update(grads, opt_state,
-                                                      params)
-                return (apply_updates(params, updates), opt_state), loss
+                with jax.named_scope("loss_grad"):
+                    loss, grads = jax.value_and_grad(masked_loss)(
+                        params, x, y, m)
+                with jax.named_scope("proximal"):
+                    grads = proximal_grad(grads, params, global_params, mu)
+                with jax.named_scope("optimizer"):
+                    updates, opt_state = optimizer.update(grads, opt_state,
+                                                          params)
+                    params = apply_updates(params, updates)
+                return (params, opt_state), loss
 
             # XLA:CPU executes while-loops serially with poor fusion —
             # unrolling the (short) local-epoch scan is ~15x faster there
@@ -253,52 +263,83 @@ class VectorizedExecutor:
             return jnp.asarray(arr)
         return jax.device_put(arr, NamedSharding(self.mesh, cohort_spec()))
 
-    def _train_group(self, cids: Sequence[str], datasets,
-                     global_params: Pytree, mu: float,
-                     seeds: Sequence[int]) -> Tuple[Pytree, jnp.ndarray]:
-        """One bucketed vmap dispatch: (stacked out_params, losses) with
-        K padded to the power-of-two bucket (rows ≥ len(cids) are pads;
-        on a mesh the bucket also rounds up to the device count)."""
+    def _stage(self, datasets, seeds, round_number: int):
+        """Host gather of each client's (T, B) batches, padded to the
+        bucket, placed on device: -> (xs, ys, ms) device operands.  The
+        ``fl.stage`` span covers it; ``bytes`` is what crosses."""
         cfg = self.task.config
-        xs, ys, ms = [], [], []
-        for cid, ds, seed in zip(cids, datasets, seeds):
-            rng = np.random.default_rng(seed)
-            idx, mask = _batch_indices(len(ds), cfg.batch_size, cfg.epochs,
-                                       rng)
-            xs.append(ds.x[idx])        # (T, B, ...)
-            ys.append(ds.y[idx])
-            ms.append(mask)
-        xs, ys, ms = np.stack(xs), np.stack(ys), np.stack(ms)
         devices = int(self.mesh.size) if self.mesh is not None else 1
-        pad = _bucket(len(cids), devices) - len(cids)
-        if pad:
-            xs = np.concatenate([xs, np.repeat(xs[-1:], pad, axis=0)])
-            ys = np.concatenate([ys, np.repeat(ys[-1:], pad, axis=0)])
-            ms = np.concatenate([ms, np.repeat(ms[-1:], pad, axis=0)])
+        bucket = _bucket(len(datasets), devices)
+        with spans.span("fl.stage", round=round_number,
+                        clients=len(datasets), bucket=bucket) as stage:
+            with spans.span("fl.gather"):
+                xs, ys, ms = [], [], []
+                for ds, seed in zip(datasets, seeds):
+                    rng = np.random.default_rng(seed)
+                    idx, mask = _batch_indices(len(ds), cfg.batch_size,
+                                               cfg.epochs, rng)
+                    xs.append(ds.x[idx])        # (T, B, ...)
+                    ys.append(ds.y[idx])
+                    ms.append(mask)
+                xs, ys, ms = np.stack(xs), np.stack(ys), np.stack(ms)
+                pad = bucket - len(datasets)
+                if pad:
+                    xs = np.concatenate([xs, np.repeat(xs[-1:], pad, axis=0)])
+                    ys = np.concatenate([ys, np.repeat(ys[-1:], pad, axis=0)])
+                    ms = np.concatenate([ms, np.repeat(ms[-1:], pad, axis=0)])
+            nbytes = xs.nbytes + ys.nbytes + ms.nbytes
+            stage.set_metadata(bytes=nbytes)
+            spans.count("staged_bytes", nbytes)
+            with spans.span("fl.put", bytes=nbytes):
+                return self._place(xs), self._place(ys), self._place(ms)
+
+    def _dispatch_key(self, mu: float, xs, ys) -> bool:
+        """Record the dispatch signature; True when it is new (a
+        compile for the current mesh)."""
         mesh_key = self._mesh_key()
         key = (mu, mesh_key, xs.shape, str(xs.dtype), ys.shape,
                str(ys.dtype))
-        if key not in self._dispatch_keys:
-            self._dispatch_keys.add(key)
-            self._compile_counts[mesh_key] = \
-                self._compile_counts.get(mesh_key, 0) + 1
+        if key in self._dispatch_keys:
+            return False
+        self._dispatch_keys.add(key)
+        self._compile_counts[mesh_key] = \
+            self._compile_counts.get(mesh_key, 0) + 1
+        spans.count("compiles")
+        return True
+
+    def _train_group(self, datasets, global_params: Pytree, mu: float,
+                     seeds: Sequence[int], round_number: int = 0,
+                     flatten: bool = False):
+        """One bucketed vmap dispatch: (stacked out_params, losses) with
+        K padded to the power-of-two bucket (rows ≥ len(cids) are pads;
+        on a mesh the bucket also rounds up to the device count).  With
+        ``flatten`` the (K, P) flat matrix is enqueued too and returned
+        third."""
+        xs, ys, ms = self._stage(datasets, seeds, round_number)
+        new_shape = self._dispatch_key(mu, xs, ys)
         if self.mesh is not None:
             # replicate over the mesh: an unsharded merge leaves the
             # params committed to one device, which the dispatch refuses
             global_params = jax.device_put(global_params,
                                            NamedSharding(self.mesh, P()))
-        return self._group_fn(mu)(
-            global_params, self._place(xs), self._place(ys), self._place(ms))
+        with spans.span("fl.dispatch", round=round_number,
+                        bucket=int(xs.shape[0]), new_shape=int(new_shape)):
+            out_params, losses = self._group_fn(mu)(global_params, xs, ys,
+                                                    ms)
+            if not flatten:
+                return out_params, losses
+            return out_params, losses, self._flatten(out_params)
 
     def run_group(self, cids: Sequence[str], datasets, global_params: Pytree,
-                  mu: float, seeds: Sequence[int]
+                  mu: float, seeds: Sequence[int], round_number: int = 0
                   ) -> Dict[str, Tuple[Pytree, float]]:
         """Train one same-shape group; returns cid -> (params, mean loss)."""
-        out_params, losses = self._train_group(cids, datasets, global_params,
-                                               mu, seeds)
+        out_params, losses = self._train_group(datasets, global_params, mu,
+                                               seeds, round_number)
         # one batched transfer for the whole loss vector — K per-scalar
         # float(losses[k]) syncs were K blocking round-trips
-        losses_np = np.asarray(losses)
+        with spans.sync("loss", losses.nbytes):
+            losses_np = np.asarray(losses)
         results = {}
         for k, cid in enumerate(cids):
             params_k = jax.tree_util.tree_map(lambda l: l[k], out_params)
@@ -307,16 +348,16 @@ class VectorizedExecutor:
 
     def run_group_batch(self, cids: Sequence[str], datasets,
                         global_params: Pytree, mu: float,
-                        seeds: Sequence[int]) -> DeviceUpdateBatch:
+                        seeds: Sequence[int],
+                        round_number: int = 0) -> DeviceUpdateBatch:
         """Device-pipeline twin of `run_group`: the trained stack is
         flattened on device into the (K_bucket, P) ravel-layout matrix
         and returned as a DeviceUpdateBatch — nothing crosses to the
         host until a consumer materializes a row.  On a mesh the matrix
         rows stay sharded over 'clients', ready for the sharded merge."""
-        out_params, losses = self._train_group(cids, datasets, global_params,
-                                               mu, seeds)
-        return DeviceUpdateBatch(self._flatten(out_params), cids,
-                                 self._unravel_for(out_params),
+        out_params, losses, mat = self._train_group(
+            datasets, global_params, mu, seeds, round_number, flatten=True)
+        return DeviceUpdateBatch(mat, cids, self._unravel_for(out_params),
                                  losses=losses)
 
     # ------------------------------------------------------------------
@@ -338,10 +379,12 @@ class VectorizedExecutor:
         for group_cids in self._group(pool, cids).values():
             datasets = [pool.clients[c].dataset for c in group_cids]
             seeds = [pool.client_seed(c, round_number) for c in group_cids]
-            out_params, _losses = self._train_group(
-                group_cids, datasets, global_params, pool.proximal_mu, seeds)
-            if pipeline_enabled():
-                self._flatten(out_params).block_until_ready()
+            flatten = pipeline_enabled()
+            out = self._train_group(datasets, global_params,
+                                    pool.proximal_mu, seeds, round_number,
+                                    flatten=flatten)
+            if flatten:
+                out[2].block_until_ready()
         return self.compile_count
 
     def run_clients(self, pool, cids: Sequence[str], global_params: Pytree,
@@ -360,44 +403,35 @@ class VectorizedExecutor:
         for group_cids in self._group(pool, cids).values():
             datasets = [pool.clients[c].dataset for c in group_cids]
             seeds = [pool.client_seed(c, round_number) for c in group_cids]
-            # wall-clock telemetry only — never folded into virtual time
-            t0 = (time.perf_counter()  # repro-lint: disable=DET002
-                  if self.collect_timing else None)
             if pipeline_enabled():
                 batch = self.run_group_batch(group_cids, datasets,
                                              global_params,
-                                             pool.proximal_mu, seeds)
+                                             pool.proximal_mu, seeds,
+                                             round_number)
                 if not overlap:
-                    jax.block_until_ready((batch.mat, batch._losses))
-                dispatch_s = self._lap(t0)
-                for i, cid in enumerate(group_cids):
-                    ds = pool.clients[cid].dataset
-                    update = pool.package_update(cid, None, round_number,
-                                                 global_params,
-                                                 batch=batch, row=i)
-                    update.dispatch_s = dispatch_s
-                    results[cid] = (update,
-                                    self.task.nominal_work_seconds(ds))
+                    with spans.sync("block", 0):
+                        jax.block_until_ready((batch.mat, batch._losses))
+                with spans.span("fl.package", round=round_number,
+                                clients=len(group_cids)):
+                    for i, cid in enumerate(group_cids):
+                        ds = pool.clients[cid].dataset
+                        update = pool.package_update(cid, None, round_number,
+                                                     global_params,
+                                                     batch=batch, row=i)
+                        results[cid] = (update,
+                                        self.task.nominal_work_seconds(ds))
                 continue
             trained = self.run_group(group_cids, datasets, global_params,
-                                     pool.proximal_mu, seeds)
-            dispatch_s = self._lap(t0)
-            for cid in group_cids:
-                params, _loss = trained[cid]
-                ds = pool.clients[cid].dataset
-                # pool.package_update runs the optional compression stage
-                # (same hook as the eager work_fn path)
-                update = pool.package_update(cid, params, round_number,
-                                             global_params)
-                update.dispatch_s = dispatch_s
-                results[cid] = (update,
-                                self.task.nominal_work_seconds(ds))
+                                     pool.proximal_mu, seeds, round_number)
+            with spans.span("fl.package", round=round_number,
+                            clients=len(group_cids)):
+                for cid in group_cids:
+                    params, _loss = trained[cid]
+                    ds = pool.clients[cid].dataset
+                    # pool.package_update runs the optional compression
+                    # stage (same hook as the eager work_fn path)
+                    update = pool.package_update(cid, params, round_number,
+                                                 global_params)
+                    results[cid] = (update,
+                                    self.task.nominal_work_seconds(ds))
         return results
-
-    def _lap(self, t0: Optional[float]) -> Optional[float]:
-        """Elapsed wall seconds since ``t0`` when timing is on."""
-        if t0 is None:
-            return None
-        self.last_dispatch_s = \
-            time.perf_counter() - t0  # repro-lint: disable=DET002
-        return self.last_dispatch_s

@@ -118,10 +118,6 @@ class ExperimentConfig:
     # merge_devices so a round never funnels through one device.  Only
     # meaningful when `vectorized` resolves on.
     executor_devices: int = 0
-    # stamp each executor group dispatch's wall-clock launch latency onto
-    # its ClientUpdates / attempt trace records as `dispatch_s`
-    # (only-when-set: default traces stay byte-identical)
-    dispatch_timing: bool = False
     # round-pipeline compilation surface (launch/compile_cache.py):
     # a directory enables JAX's persistent compilation cache, so repeat
     # runs (and CI) skip XLA compiles entirely — ignored when
@@ -218,8 +214,8 @@ def run_experiment(task: ClassificationTask,
         vectorized = jax.default_backend() != "cpu"
     if vectorized:
         # the executor is cached on the task (shared across experiment
-        # grids), so both knobs are set unconditionally — a later run
-        # with defaults must not inherit a previous run's mesh/timing
+        # grids), so the mesh is set unconditionally — a later run with
+        # defaults must not inherit a previous run's mesh
         from ..launch.mesh import make_clients_mesh
         mesh = (make_clients_mesh(config.executor_devices)
                 if config.executor_devices and config.executor_devices > 1
@@ -228,7 +224,6 @@ def run_experiment(task: ClassificationTask,
         # the devices that exist (a size-1 mesh falls back to the
         # identical single-device vmap path)
         pool.executor.configure_mesh(mesh)
-        pool.executor.collect_timing = bool(config.dispatch_timing)
 
     scheduler = None
     if config.scheduler is not None:

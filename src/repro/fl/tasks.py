@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import spans
 from ..data.loader import batches
 from ..data.synthetic import ArrayDataset
 from ..models.small import ModelDef
@@ -76,7 +77,8 @@ class ClassificationTask:
                 params, opt_state, loss = self._train_step(
                     params, opt_state, global_params,
                     jnp.asarray(x), jnp.asarray(y), float(mu))
-                losses.append(float(loss))
+                with spans.sync("loss", loss.nbytes):
+                    losses.append(float(loss))
         return params, float(np.mean(losses)) if losses else 0.0
 
     # ------------------------------------------------------------------
@@ -95,8 +97,9 @@ class ClassificationTask:
             x = jnp.asarray(ds.x[i:i + batch_size])
             y = jnp.asarray(ds.y[i:i + batch_size])
             c, l = self._eval_batch(params, x, y)
-            correct += float(c)
-            loss_sum += float(l)
+            with spans.sync("eval", c.nbytes + l.nbytes):
+                correct += float(c)
+                loss_sum += float(l)
             n += x.shape[0]
         return correct / max(1, n), loss_sum / max(1, n)
 

@@ -73,6 +73,7 @@ def int8_encode(x: jnp.ndarray, chunk: int = 256, tile_r: int = 8,
         out_shape=[jax.ShapeDtypeStruct((n_rows, chunk), jnp.int8),
                    jax.ShapeDtypeStruct((n_rows, 1), jnp.float32)],
         interpret=interpret,
+        name="int8_encode",
     )(xm)
     return q[:n_chunks], scale[:n_chunks, 0]
 
@@ -100,6 +101,7 @@ def int8_decode(q: jnp.ndarray, scale: jnp.ndarray, length: int,
         out_specs=pl.BlockSpec((tile_r, chunk), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rows, chunk), jnp.float32),
         interpret=interpret,
+        name="int8_decode",
     )(qm, sm)
     return out.reshape(-1)[:length]
 
@@ -144,6 +146,7 @@ def topk_mask(x: jnp.ndarray, tau: jnp.ndarray, last_keep: jnp.ndarray,
         out_specs=pl.BlockSpec((1, tile_p), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_tiles * tile_p), jnp.float32),
         interpret=interpret,
+        name="topk_mask",
     )(scal, idx, xr)
     return out[0, :P]
 

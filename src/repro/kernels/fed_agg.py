@@ -76,6 +76,7 @@ def _fed_agg_impl(updates: jnp.ndarray, coeffs: jnp.ndarray,
         out_specs=pl.BlockSpec((1, tile_p), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_tiles * tile_p), updates.dtype),
         interpret=interpret,
+        name="fed_agg",
     )(coeffs2, updates)
     return out[0, :P]
 
@@ -207,6 +208,7 @@ def _fed_agg_apply_impl(updates: jnp.ndarray, coeffs: jnp.ndarray,
         out_shape=[vec, vec, vec,
                    jax.ShapeDtypeStruct((1, n_tiles * _LANES), jnp.float32)],
         interpret=interpret,
+        name="fed_agg_apply",
     )(scal, coeffs2, updates, g2, m2, v2)
     norm = jnp.sqrt(jnp.sum(sq.reshape(n_tiles, _LANES)[:, 0]))
     return out[0, :P], m_new[0, :P], v_new[0, :P], norm

@@ -8,8 +8,7 @@ first INVOKE_START (``REPRO_OVERLAP_DISPATCH``) leaves every golden
 trace byte-identical — virtual time never observes the wall clock.
 Plus the riding satellites: mesh-keyed jit caches / per-mesh compile
 accounting, the lazy once-only ``work_provider`` hook on the event
-engine, and ``dispatch_s`` timing fields that appear only when asked
-for.
+engine.
 """
 import hashlib
 import subprocess
@@ -29,7 +28,7 @@ from repro.data.synthetic import ArrayDataset
 from repro.faas import CostMeter, FaaSConfig, MockInvoker, SimulatedFaaSPlatform
 from repro.faas.events import EventQueue
 from repro.faas.invoker import InvocationEngine
-from repro.faas.trace import REC_ATTEMPT, TraceRecorder
+from repro.faas.trace import TraceRecorder
 from repro.fl.client import ClientPool
 from repro.fl.controller import TrainingDriver
 from repro.fl.executor import VectorizedExecutor, _bucket
@@ -90,7 +89,6 @@ def _run(task, parts, strategy_name, mode, n_rounds=2):
     drv, pool = _driver(task, parts, strategy_name, mode, trace=trace)
     # the executor is cached on the task across drivers: pin defaults
     pool.executor.configure_mesh(None)
-    pool.executor.collect_timing = False
     params, _res = drv.run(task.init_params(0), n_rounds)
     return _digest(params), trace.dumps().encode()
 
@@ -241,52 +239,6 @@ def test_work_provider_none_falls_back_to_work_fn():
             break
         engine.handle(queue, ev)
     assert sorted(wf_calls) == ["a", "b"]
-
-
-# ----------------------------------------------------------------------
-# dispatch timing: only-when-set
-# ----------------------------------------------------------------------
-def _attempts(trace_bytes):
-    import json
-    return [json.loads(line) for line in trace_bytes.decode().splitlines()
-            if json.loads(line).get("type") == REC_ATTEMPT]
-
-
-def test_dispatch_timing_absent_by_default(setup):
-    task, parts = setup
-    _, trace_bytes = _run(task, parts, "fedavg", "sync")
-    atts = _attempts(trace_bytes)
-    assert atts
-    assert all("dispatch_s" not in a for a in atts)
-
-
-def test_dispatch_timing_present_when_collected(setup):
-    task, parts = setup
-    trace = TraceRecorder()
-    drv, pool = _driver(task, parts, "fedavg", "sync", trace=trace)
-    pool.executor.configure_mesh(None)
-    pool.executor.collect_timing = True
-    try:
-        drv.run(task.init_params(0), 2)
-    finally:
-        pool.executor.collect_timing = False
-    atts = _attempts(trace.dumps().encode())
-    timed = [a for a in atts if "dispatch_s" in a]
-    assert timed                             # vectorized cohort attempts
-    assert all(isinstance(a["dispatch_s"], float)
-               and a["dispatch_s"] >= 0.0 for a in timed)
-    assert pool.executor.last_dispatch_s is not None
-
-
-def test_update_record_round_trips_dispatch_s():
-    from repro.core.aggregation import update_from_record, update_to_record
-    upd = ClientUpdate("c", {"w": jnp.zeros(2)}, 4, 1, dispatch_s=0.25)
-    rec = update_to_record(upd)
-    assert rec["dispatch_s"] == 0.25
-    back = update_from_record(rec, {"w": jnp.zeros(2)})
-    assert back.dispatch_s == 0.25
-    dense = update_to_record(ClientUpdate("c", {"w": jnp.zeros(2)}, 4, 1))
-    assert "dispatch_s" not in dense         # only-when-set
 
 
 # ----------------------------------------------------------------------
