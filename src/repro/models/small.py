@@ -6,6 +6,14 @@
 - Speech:   2×[conv3x3, conv3x3, maxpool, dropout(.25)] → avgpool → FC(35)
 
 Functional (init, apply) pairs; params are plain dict pytrees.
+
+Each LSTM layer runs time-major, (T, B, ·).  The input projection of all
+T·B positions is one product before the forward time loop, which adds
+only ``h @ wh`` per step; in the backward loop (a ``custom_vjp``) the
+input gradient ``dg @ wx.T`` is likewise one product after it.  The weight
+and bias gradients stay per-step sums in the loop, in the order plain
+autodiff of the per-step LSTM adds them, so training rounds exactly as it
+does.
 """
 from __future__ import annotations
 
@@ -92,22 +100,63 @@ def _lstm_init(rng, n_in, hidden):
             "b": jnp.zeros((4 * hidden,))}
 
 
+def _lstm_cell(z, c):
+    """One timestep from its gate pre-activations ``z``: gate order
+    (i, f, g, o), +1.0 on the forget gate's pre-activation."""
+    i, f, g, o = jnp.split(z, 4, axis=-1)
+    c = jax.nn.sigmoid(f + 1.0) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
+    return jax.nn.sigmoid(o) * jnp.tanh(c), c
+
+
+def _lstm_run(p, xs, keep):
+    """Scan over time-major ``xs`` (T, B, n_in).  The input projection of
+    every timestep is one product before the loop; each step adds only
+    ``h @ wh``.  ``keep(h, c_prev, z)`` picks what each step stacks."""
+    gx = xs @ p["wx"]
+
+    def step(carry, gx_t):
+        h, c_prev = carry
+        z = gx_t + h @ p["wh"] + p["b"]
+        h, c = _lstm_cell(z, c_prev)
+        return (h, c), keep(h, c_prev, z)
+
+    zero = jnp.zeros((xs.shape[1], p["wh"].shape[0]), xs.dtype)
+    return lax.scan(step, (zero, zero), gx)[1]
+
+
+@jax.custom_vjp
 def _lstm_scan(p, xs):
-    """xs: (B, T, n_in) → outputs (B, T, hidden)."""
-    hidden = p["wh"].shape[0]
-    B = xs.shape[0]
+    """xs: (T, B, n_in), time-major → hidden states (T, B, hidden)."""
+    return _lstm_run(p, xs, lambda h, c_prev, z: h)
 
-    def step(carry, x_t):
-        h, c = carry
-        gates = x_t @ p["wx"] + h @ p["wh"] + p["b"]
-        i, f, g, o = jnp.split(gates, 4, axis=-1)
-        c = jax.nn.sigmoid(f + 1.0) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
-        h = jax.nn.sigmoid(o) * jnp.tanh(c)
-        return (h, c), h
 
-    init = (jnp.zeros((B, hidden), xs.dtype), jnp.zeros((B, hidden), xs.dtype))
-    (_, _), out = lax.scan(step, init, jnp.swapaxes(xs, 0, 1))
-    return jnp.swapaxes(out, 0, 1)
+def _lstm_scan_fwd(p, xs):
+    hs, c_prev, zs = _lstm_run(p, xs, lambda h, c_prev, z: (h, c_prev, z))
+    return hs, (p, xs, hs, c_prev, zs)
+
+
+def _lstm_scan_bwd(res, d_hs):
+    """The reverse scan adds each step's weight and bias gradients to its
+    carry in the order plain autodiff of the per-step LSTM does, so the
+    sums round alike; the input gradient is one product after it."""
+    p, xs, hs, c_prev, zs = res
+    h_prev = jnp.concatenate([jnp.zeros_like(hs[:1]), hs[:-1]])
+
+    def step(carry, t_in):
+        dh, dc, dwh, dwx, db = carry
+        dh_t, z_t, c_p, h_p, x_t = t_in
+        dz, dc = jax.vjp(_lstm_cell, z_t, c_p)[1]((dh + dh_t, dc))
+        return (dz @ p["wh"].T, dc, dwh + h_p.T @ dz, dwx + x_t.T @ dz,
+                db + jnp.sum(dz, axis=0)), dz
+
+    zero = jnp.zeros_like(hs[0])
+    init = (zero, zero) + tuple(jnp.zeros_like(p[k]) for k in ("wh", "wx", "b"))
+    (_, _, dwh, dwx, db), dz = lax.scan(
+        step, init, (d_hs, zs, c_prev, h_prev, xs), reverse=True)
+    return {"wx": dwx, "wh": dwh, "b": db}, dz @ p["wx"].T
+
+
+_lstm_scan.defvjp(_lstm_scan_fwd, _lstm_scan_bwd)
 
 
 def make_char_lstm(vocab: int = 82, embed: int = 8,
@@ -124,10 +173,12 @@ def make_char_lstm(vocab: int = 82, embed: int = 8,
         }
 
     def apply(params, tokens):  # (B, T) int32 → (B, vocab)
-        h = params["embed"][tokens]
+        # gathered batch-major, so the embedding gradient's scatter-add
+        # keeps its order; the layers run time-major, (T, B, embed)
+        h = jnp.swapaxes(params["embed"][tokens], 0, 1)
         h = _lstm_scan(params["lstm1"], h)
         h = _lstm_scan(params["lstm2"], h)
-        return _dense(params["out"], h[:, -1, :])
+        return _dense(params["out"], h[-1])
 
     return ModelDef(init, apply, name)
 
