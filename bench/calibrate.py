@@ -11,7 +11,8 @@ then the numbers of fedbench/checks.py for the program (the lower
 readings).  For the first `--control` seeds also the same numbers for
 the bfloat16 reference put in the program's place (the control), and for
 the reference with a planted fault of local training: half of every
-batch left out, and a step that returns the params unchanged.  For the
+batch left out, a step that returns the params unchanged, and, in a cell
+of several chips, a merge with the exchange between chips left out.  For the
 first `--witness` seeds also the reference at `highest` matmul precision
 in the program's place, and the program against a reference at
 `highest`: how far precision alone moves each number.  `--rounds` sets the checked round of
@@ -20,6 +21,7 @@ per seed and reading, with the median client's training numbers beside
 the worst's, and last the limits that `propose` sets from them; the
 limits in bench/limits/ are set from these readings, as PERF.md records.
 """
+import json
 import os
 import sys
 import time
@@ -35,7 +37,7 @@ sys.path.insert(0, str(BENCH))
 BUILT_IN = {"eval_acc_gap": 0.1}
 # how many times the lower reading a stand-in's least reading must be to
 # set the upper one
-RATIO = {"control": 3, "unchanged": 3, "half_batch": 10}
+RATIO = {"control": 3, "unchanged": 3, "half_batch": 10, "no_exchange": 10}
 
 
 def propose(rows):
@@ -66,9 +68,35 @@ def propose(rows):
     return out
 
 
+def readings(bench, seed: int, control: bool, witness: bool,
+             chips: int) -> list:
+    """The rows of one seed's study, each printed as it is read.  The
+    checkers, and the clients the reference trained on the chips, are let
+    go of on return, before the next seed's study."""
+    readers = [("program", bench.checker(), "program")]
+    chk = readers[0][1]
+    if control:
+        readers += [(k, chk, k) for k in RATIO
+                    if k != "no_exchange" or chips > 1]
+    if witness:
+        hi = bench.checker("highest")
+        hi.trained = chk.trained
+        readers += [("highest", chk, "highest"),
+                    ("program_vs_highest", hi, "program")]
+    rows = []
+    for name, chk, impl in readers:
+        nums = chk.numbers(bench.cap, impl=impl)
+        median = {f"median_{k}": v for k, v in chk.median.items()}
+        rows.append({"seed": seed, "reading": name,
+                     "round_t": bench.cap.round_t,
+                     "round_e": bench.cap.round_e, **nums, **median,
+                     "merge_error": chk.merge_error})
+        print(json.dumps(rows[-1]), flush=True)
+    return rows
+
+
 def main() -> int:
     import argparse
-    import json
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
@@ -79,11 +107,11 @@ def main() -> int:
     from fedbench import harness
     from fedbench.registry import Cell, _json
     cell = Cell(args.workload)
-    harness.check_devices(cell.chips, _json(BENCH / "peaks.json"))
+    devices = harness.check_devices(cell.chips, _json(BENCH / "peaks.json"))
     sys.path.insert(0, str(BENCH.parent / "src"))
     from repro.launch.compile_cache import enable_compilation_cache
     enable_compilation_cache()
-    bench = harness.Bench(cell)
+    bench = harness.Bench(cell, devices=devices)
     rounds = [int(r) for r in args.rounds.split(",") if r]
     rows = []
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
@@ -92,23 +120,9 @@ def main() -> int:
         if rounds:
             bench.cap.round_t = rounds[i % len(rounds)]
         bench.study()
-        readings = [("program", bench.checker(), "program")]
-        chk = readings[0][1]
-        if i < args.control:
-            readings += [(k, chk, k) for k in RATIO]
-        if i < args.witness:
-            hi = bench.checker("highest")
-            hi.trained = chk.trained
-            readings += [("highest", chk, "highest"),
-                         ("program_vs_highest", hi, "program")]
-        for name, chk, impl in readings:
-            nums = chk.numbers(bench.cap, impl=impl)
-            median = {f"median_{k}": v for k, v in chk.median.items()}
-            rows.append({"seed": seed, "reading": name,
-                         "round_t": bench.cap.round_t,
-                         "round_e": bench.cap.round_e, **nums, **median,
-                         "merge_error": chk.merge_error})
-            print(json.dumps(rows[-1]), flush=True)
+        rows += readings(bench, seed, control=i < args.control,
+                         witness=i < args.witness,
+                         chips=len(devices))
         print(f"calibrate: seed {seed} {time.perf_counter() - t:.1f} s",
               file=sys.stderr, flush=True)
     print(json.dumps({"limits": propose(rows)}), flush=True)

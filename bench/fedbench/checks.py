@@ -46,7 +46,11 @@ updates differs from the program's by the training's spread, not by the
 merge's (PERF.md, Findings).
 
 The reference runs at float32 with the matmul precision the
-configuration states.
+configuration states.  In a cell of several chips it spreads its blocks of
+clients over them (reference.train_clients), each block whole on one chip,
+and compares each client on its block's chip; the merge and evaluation are
+compared on the first chip.  What the probes kept (device arrays, or numpy
+in host mode) is put on the chip where it is compared.
 """
 from __future__ import annotations
 
@@ -63,11 +67,15 @@ from . import scenario
 NUMBERS = ("update_gap", "loss_gap", "cohort_loss_gap", "merge_gap",
            "eval_loss_gap", "eval_acc_gap")
 # what can stand in the program's place: dtype, matmul precision (None:
-# the configuration's), and a planted fault of local training
+# the configuration's), and a planted fault of local training or, for
+# "no_exchange", of the merge: each chip sums only the update rows it
+# holds, and the first chip's partial sum is the result
 IMPLS = {"control": (jnp.bfloat16, None, None),
          "half_batch": (R.F32, None, "half_batch"),
          "unchanged": (R.F32, None, "frozen"),
+         "no_exchange": (R.F32, None, "no_exchange"),
          "highest": (R.F32, "highest", None)}
+TRAINING_FAULTS = ("half_batch", "frozen")
 
 
 def leaf_gap(d_impl, d_ref) -> float:
@@ -125,15 +133,19 @@ class Checker:
     """Compares one study's captured rounds with the reference."""
 
     def __init__(self, ref_module, cfg: dict, traffic: dict, train: dict,
-                 test: dict, study_seed: int, precision=None):
+                 test: dict, study_seed: int, precision=None,
+                 devices=None, block: int = R.BLOCK):
         """train / test: client id -> the dataset handed to the program
         (its numpy arrays `x` and `y`); study_seed: the seed the study ran with (its scenario's draw);
         precision: the reference's matmul precision, by default the
-        configuration's."""
+        configuration's; devices: the cell's chips (by default JAX's
+        first); block: clients the reference trains at once."""
         self.ref, self.cfg, self.traffic = ref_module, cfg, traffic
         self.train, self.test = train, test
         self.precision = precision or cfg["precision"]["matmul"]
         self.study_seed = study_seed
+        self.devices = list(devices or jax.devices()[:1])
+        self.block = block
         self.trainers: Dict = {}
         self.evaluators: Dict = {}
         # (dtype, fault, precision, round, client) -> (params, loss);
@@ -151,6 +163,7 @@ class Checker:
         """Local training of `cids` of round `rnd` from `params` by the
         reference ("reference") or a stand-in, cached per client."""
         dtype, prec, fault = self._impl(impl)
+        fault = fault if fault in TRAINING_FAULTS else None
         key = (dtype, fault)
         if key not in self.trainers:
             self.trainers[key] = R.make_trainer(
@@ -161,7 +174,8 @@ class Checker:
             done = R.train_clients(
                 self.trainers[key], params,
                 [(self.train[c].x, self.train[c].y, cap.seeds[rnd][c])
-                 for c in todo], self.cfg, dtype, prec)
+                 for c in todo], self.cfg, dtype, prec, self.block,
+                self.devices if len(self.devices) > 1 else None)
             for c, r in zip(todo, done):
                 self.trained[(key, prec, rnd, c)] = r
         return [self.trained[(key, prec, rnd, c)] for c in cids]
@@ -176,20 +190,23 @@ class Checker:
 
     # ------------------------------------------------------------------
     def numbers(self, cap, impl: str = "program") -> Dict[str, float]:
-        """The five numbers for `impl`: "program", or one of IMPLS put in
+        """The six numbers for `impl`: "program", or one of IMPLS put in
         the program's place ("control": the bfloat16 reference;
         "half_batch": the reference with half of every batch left out in
-        local training; "highest": the reference at `highest` matmul
-        precision)."""
+        local training; "unchanged": local steps that return their
+        params; "no_exchange": the merge of the first chip's rows alone;
+        "highest": the reference at `highest` matmul precision)."""
         if impl != "program" and impl not in IMPLS:
             raise ValueError(impl)
         program = impl == "program"
+        fault = self._impl(impl)[2]
         res = cap.result
         t, e = cap.round_t, cap.round_e
         n_rounds = len(res.rounds)
-        g_t = cap.params[t]
-        g_next = (cap.params[t + 1] if t + 1 < n_rounds
-                  else res.final_params)
+        home = self.devices[0]
+        g_t = jax.device_put(cap.params[t], home)
+        g_next = jax.device_put(cap.params[t + 1] if t + 1 < n_rounds
+                                else res.final_params, home)
         _, unravel = ravel_pytree(g_t)
         out: Dict[str, float] = {}
 
@@ -197,12 +214,17 @@ class Checker:
         cids = sorted(cap.seeds[t])
         ref = self._updates("reference", g_t, t, cap, cids)
         if program:
-            imp = [(unravel(b.row(r)), b.loss(r))
+            imp = [(unravel(jax.device_put(b.row(r), home)), b.loss(r))
                    for b, r in (cap.rows[t][c] for c in cids)]
         else:
             imp = self._updates(impl, g_t, t, cap, cids)
-        upd = [leaf_gap(_sub(ip, g_t), _sub(rp, g_t))
-               for (ip, _), (rp, _) in zip(imp, ref)]
+        upd = []
+        for (ip, _), (rp, _) in zip(imp, ref):
+            # on the chip where the reference trained this client
+            dev, = jax.tree_util.tree_leaves(rp)[0].devices()
+            g = jax.device_put(g_t, dev)
+            upd.append(leaf_gap(_sub(jax.device_put(ip, dev), g),
+                                _sub(rp, g)))
         loss = [_rel(il, rl) for (_, il), (_, rl) in zip(imp, ref)]
         out["update_gap"], out["loss_gap"] = max(upd), max(loss)
         out["cohort_loss_gap"] = _rel(sum(il for _, il in imp),
@@ -225,11 +247,13 @@ class Checker:
         if entries:
             coeffs = R.staleness_coefficients(
                 [(p, len(self.train[c])) for c, p in entries], t)
-            rows = [cap.rows[p][c][0].row(cap.rows[p][c][1])
-                    for c, p in entries]
+            rows = [jax.device_put(cap.rows[p][c][0].row(cap.rows[p][c][1]),
+                                   home) for c, p in entries]
             want = R.weighted_sum(rows, coeffs, R.F32)
             if program:
                 got = ravel_pytree(g_next)[0]
+            elif fault == "no_exchange":
+                got = self._first_chip_sum(cap, entries, rows, coeffs)
             else:
                 got = R.weighted_sum(rows, coeffs, self._impl(impl)[0])
             g_flat = ravel_pytree(g_t)[0]
@@ -242,8 +266,8 @@ class Checker:
             out["merge_gap"] = 0.0 if same else float("inf")
 
         # ---- evaluation after round e ---------------------------------
-        g_eval = (cap.params[e + 1] if e + 1 < n_rounds
-                  else res.final_params)
+        g_eval = jax.device_put(cap.params[e + 1] if e + 1 < n_rounds
+                                else res.final_params, home)
         cid_of = {id(ds): cid for cid, ds in self.test.items()}
         per = []
         for ds, acc_p, loss_p in cap.evals:
@@ -275,3 +299,16 @@ class Checker:
         out["eval_loss_gap"] = max(loss_gap)
         out["eval_acc_gap"] = max(acc_gap)
         return out
+
+    def _first_chip_sum(self, cap, entries, rows, coeffs):
+        """The merge with the exchange between chips left out: the
+        program's update rows are split over the chips in equal runs of
+        its bucket, and only the first chip's share is summed."""
+        keep = []
+        for (c, p), row, coeff in zip(entries, rows, coeffs):
+            batch, i = cap.rows[p][c]
+            if i < batch.mat.shape[0] // len(self.devices):
+                keep.append((row, coeff))
+        if not keep:
+            return jnp.zeros_like(rows[0])
+        return R.weighted_sum(*zip(*keep), R.F32)

@@ -148,12 +148,47 @@ def merged(base: dict, overrides: Optional[dict]) -> dict:
     return out
 
 
+# a block of the reference's clients may take a quarter of the chip's
+# memory, the probes' captures an eighth
+BLOCK_SHARE, PROBE_SHARE = 4, 8
+# optimizer state per parameter, in copies of the params
+OPT_SLOTS = {"sgd": 0, "adam": 2}
+
+
+def chip_bytes(device) -> Optional[int]:
+    """The memory JAX may use on `device`; None where it reports none."""
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
+def check_sizes(cfg: dict, traffic: dict, device) -> tuple:
+    """How the check fits the chip, from the configuration's parameter
+    count P.  -> (clients the reference trains at once, probes keep host
+    copies).  The reference takes reference.BLOCK clients at once, or
+    fewer where the block's float32 params, gradients and optimizer state
+    would pass BLOCK_SHARE of the chip (at least 1).  The probes keep
+    host copies where the update rows of two rounds, sharded over the
+    executor's chips, and three param trees would pass PROBE_SHARE of it.
+    A device that reports no memory takes BLOCK and device references."""
+    from .reference import BLOCK
+    limit = chip_bytes(device)
+    if limit is None:
+        return BLOCK, False
+    p = cfg["params"]
+    client = 4 * p * (2 + OPT_SLOTS[cfg["local"]["optimizer"]])
+    block = max(1, min(BLOCK, limit // BLOCK_SHARE // client))
+    chips = max(1, traffic["executor_devices"])
+    held = 4 * p * (2 * traffic["cohort"] / chips + 3)
+    return block, held > limit / PROBE_SHARE
+
+
 class Bench:
     """A cell's program, probes and data.  One task (its compiled
     programs) serves every seed that `prepare` is given."""
 
     def __init__(self, cell: Cell, traffic_overrides=None,
-                 config_overrides=None):
+                 config_overrides=None, devices=None):
+        """devices: the cell's chips, on which the reference spreads its
+        blocks (by default JAX's first `cell.chips` devices)."""
         import jax
         sys.path.insert(0, str(ROOT / "src"))
         from repro.fl.client import ClientPool
@@ -162,10 +197,13 @@ class Bench:
         self.cell = cell
         self.cfg = merged(cell.config, config_overrides)
         self.traffic = merged(cell.traffic, traffic_overrides)
+        self.devices = list(devices or jax.devices()[:cell.chips])
+        self.block, host = check_sizes(self.cfg, self.traffic,
+                                       self.devices[0])
         self.n_rounds = self.traffic["rounds_per_study"]
         self.task = build_task(self.cfg)
         self._init = jax.jit(lambda k: cell.reference.init(k, self.cfg))
-        self.cap = probes.Capture(0, 0)
+        self.cap = probes.Capture(0, 0, host=host)
         # the executor is cached on the task, so every study's pool uses
         # this one, probes and all
         self.executor = ClientPool(self.task, {}).executor
@@ -191,7 +229,8 @@ class Bench:
     def checker(self, precision=None):
         from .checks import Checker
         return Checker(self.cell.reference, self.cfg, self.traffic,
-                       self.train, self.test, self.study_seed, precision)
+                       self.train, self.test, self.study_seed, precision,
+                       devices=self.devices, block=self.block)
 
     def study(self, span=None):
         import jax
@@ -205,6 +244,7 @@ class Bench:
                 res = run_experiment(self.task, self.train, self.test,
                                      self.exp, initial_params=self.init)
         jax.block_until_ready(res.final_params)
+        self.cap.collect()
         self.cap.result = res
         return res
 
@@ -233,7 +273,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     from repro.launch.compile_cache import enable_compilation_cache
     cache = enable_compilation_cache()
     counter = CompileCounter()
-    bench = Bench(cell, traffic_overrides, config_overrides)
+    bench = Bench(cell, traffic_overrides, config_overrides, devices)
     try:
         return _measure(bench, counter, devices, peak, cache, seed, seconds,
                         trace, limits, t0)
@@ -336,12 +376,16 @@ def _measure(bench, counter, devices, peak, cache, seed, seconds, trace,
 
     log(f"window {window_s:.6f} s, {len(studies)} studies, {completed} "
         f"rounds completed, {attempted} started, {failed} failed")
-    log(f"programs lowered in the window {compiles_window[0]}; executor "
-        f"compile_count "
+    log(f"programs lowered in the window {compiles_window[0]}, read from "
+        f"the cache {compiles_window[1]}; executor compile_count "
         f"{executor.compile_count}")
     log(f"round wall times: {percentile_line(times)}; max "
         f"{max(times) if times else float('nan'):.6f} s")
     log(f"peak_bytes_in_use {mem_peak} (fullest of {len(devices)} chips)")
+    log(f"probes held at their peak {cap.peak_bytes} device bytes on the "
+        f"fullest chip, {cap.peak_total} on all {len(devices)} "
+        f"({'host copies' if cap.host else 'device references'}; reference "
+        f"block {bench.block})")
     if studies:
         last = studies[-1]
         log(f"study (virtual time): mean EUR {last.mean_eur:.6f}, cost "

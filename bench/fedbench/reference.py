@@ -5,8 +5,11 @@ Straightforward jax.numpy over the configuration's own reference model
 (`configs/<name>.py`).  Nothing of the program is imported.  The float32
 reference runs at the matmul precision the configuration states
 (`precision.matmul`); the control runs the same code with every array in
-bfloat16.  Local training runs clients in blocks of BLOCK (jax.vmap over
-clients of one size), each client on its own data and sample order.
+bfloat16.  Local training runs clients in blocks (jax.vmap over clients of
+one size; BLOCK of them, fewer where a block of the configuration's
+clients would not fit the chip, harness.check_sizes), each client on its
+own data and sample order.  Labels may
+have any rank: the loss and the accuracy are over every label.
 """
 from __future__ import annotations
 
@@ -46,6 +49,18 @@ def client_order(n: int, batch: int, epochs: int, seed: int):
         full.append(perm[:nb * batch].reshape(nb, batch))
         part.append(perm[nb * batch:])
     return np.stack(full), np.stack(part)
+
+
+def label_logp(logp, y):
+    """The log-probability of every label: y of any shape, logp of y's
+    shape and one more axis, of the classes.  -> y's shape and a last
+    axis of 1."""
+    return jnp.take_along_axis(logp, y[..., None], axis=-1)
+
+
+def mean_ce(logits, y):
+    """Mean cross-entropy over every label of y."""
+    return -jnp.mean(label_logp(jax.nn.log_softmax(logits), y))
 
 
 def _optimizer(local: dict, dtype):
@@ -92,7 +107,7 @@ def make_trainer(apply, cfg: dict, dtype=F32, half_batch: bool = False,
     client axis.  Per client, xf/yf hold the full batches of every
     epoch (E, nb, B, ...), xp/yp each epoch's shorter last batch (E, r,
     ...), which is skipped when r is 0.  Each step's loss is the mean
-    cross-entropy over the samples of that batch.  Two planted faults:
+    cross-entropy over every label of that batch.  Two planted faults:
     `half_batch`, each step keeps only the first half of its batch;
     `frozen`, each step returns the params it was given."""
     opt_init, opt_step = _optimizer(cfg["local"], dtype)
@@ -101,8 +116,7 @@ def make_trainer(apply, cfg: dict, dtype=F32, half_batch: bool = False,
         if half_batch:
             keep = max(1, x.shape[0] // 2)
             x, y = x[:keep], y[:keep]
-        logp = jax.nn.log_softmax(apply(params, x, cfg))
-        return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+        return mean_ce(apply(params, x, cfg), y)
 
     grad = jax.value_and_grad(loss_fn)
 
@@ -133,62 +147,92 @@ def make_trainer(apply, cfg: dict, dtype=F32, half_batch: bool = False,
     return jax.jit(jax.vmap(train, in_axes=(None, 0, 0, 0, 0)))
 
 
-def train_clients(trainer, params, clients, cfg: dict, dtype=F32,
-                  precision: str = "default"):
-    """Local training of clients from the same `params`.  clients: a list
-    of (x, y, seed).  -> a list of (params after training, mean loss), in
-    the order given.  Clients of one size go through the trainer BLOCK at
-    a time; a short block is filled up with copies of its last client,
-    whose results are dropped."""
-    local = cfg["local"]
-    out = [None] * len(clients)
+def _blocks(clients, block: int):
+    """Client indices in blocks of `block` clients of one size, each filled
+    up to `block` with copies of its last client."""
     by_size = {}
     for i, (x, _, _) in enumerate(clients):
         by_size.setdefault(len(x), []).append(i)
     for idx in by_size.values():
-        for b0 in range(0, len(idx), BLOCK):
-            block = idx[b0:b0 + BLOCK]
-            fill = block + [block[-1]] * (BLOCK - len(block))
-            xf, yf, xp, yp = [], [], [], []
-            for i in fill:
-                x, y, seed = clients[i]
-                full, part = client_order(len(x), local["batch_size"],
-                                          local["epochs"], seed)
-                xf.append(x[full])
-                yf.append(y[full])
-                xp.append(x[part])
-                yp.append(y[part])
-            x0 = clients[block[0]][0]
-            xdt = dtype if np.issubdtype(x0.dtype, np.floating) else x0.dtype
-            with precision_scope(dtype, precision):
-                ps, losses = trainer(
-                    params, jnp.asarray(np.stack(xf), xdt),
-                    jnp.asarray(np.stack(yf)), jnp.asarray(np.stack(xp), xdt),
-                    jnp.asarray(np.stack(yp)))
+        for b0 in range(0, len(idx), block):
+            part = idx[b0:b0 + block]
+            yield part, part + [part[-1]] * (block - len(part))
+
+
+def _block_inputs(clients, fill, local: dict, dtype):
+    """The trainer's data arguments for the clients `fill`, as numpy."""
+    xf, yf, xp, yp = [], [], [], []
+    for i in fill:
+        x, y, seed = clients[i]
+        full, part = client_order(len(x), local["batch_size"],
+                                  local["epochs"], seed)
+        xf.append(x[full])
+        yf.append(y[full])
+        xp.append(x[part])
+        yp.append(y[part])
+    x0 = clients[fill[0]][0]
+    xdt = dtype if np.issubdtype(x0.dtype, np.floating) else x0.dtype
+    return xdt, np.stack(xf), np.stack(yf), np.stack(xp), np.stack(yp)
+
+
+def train_clients(trainer, params, clients, cfg: dict, dtype=F32,
+                  precision: str = "default", block: int = BLOCK,
+                  devices=None):
+    """Local training of clients from the same `params`.  clients: a list
+    of (x, y, seed).  -> a list of (params after training, mean loss), in
+    the order given.  Clients of one size go through the trainer `block`
+    at a time; a short block is filled up with copies of its last client,
+    whose results are dropped.  Given several `devices`, block b runs
+    whole on device b mod len(devices), one block per device in flight,
+    and each client's params stay on its block's device; without them
+    every block runs where JAX puts it, one at a time."""
+    devices = list(devices or [None])
+    local = cfg["local"]
+    out = [None] * len(clients)
+    placed, wave = {}, []
+
+    def drain():
+        for part, ps, losses in wave:
             ps = cast(ps, F32)
             losses = np.asarray(losses)
-            for j, i in enumerate(block):
+            for j, i in enumerate(part):
                 out[i] = (jax.tree_util.tree_map(lambda a, j=j: a[j], ps),
                           float(losses[j]))
+        wave.clear()
+
+    for b, (part, fill) in enumerate(_blocks(clients, block)):
+        dev = devices[b % len(devices)]
+        if dev not in placed:
+            placed[dev] = params if dev is None else jax.device_put(params,
+                                                                   dev)
+        xdt, xf, yf, xp, yp = _block_inputs(clients, fill, local, dtype)
+        with jax.default_device(dev), precision_scope(dtype, precision):
+            wave.append((part, *trainer(
+                placed[dev], jnp.asarray(xf, xdt), jnp.asarray(yf),
+                jnp.asarray(xp, xdt), jnp.asarray(yp))))
+        if len(wave) == len(devices):
+            drain()
+    drain()
     return out
 
 
 def make_evaluator(apply, cfg: dict, dtype=F32):
-    """jit(params, x, y) -> (correct predictions, summed cross-entropy)."""
+    """jit(params, x, y) -> (correct predictions, summed cross-entropy),
+    over every label of y."""
     def ev(params, x, y):
         logits = apply(cast(params, dtype), x, cfg)
         logp = jax.nn.log_softmax(logits).astype(F32)
-        ce = -jnp.take_along_axis(logp, y[:, None], axis=1)[:, 0]
+        ce = -label_logp(logp, y)[..., 0]
         return jnp.sum(jnp.argmax(logits, axis=-1) == y), jnp.sum(ce)
     return jax.jit(ev)
 
 
 def evaluate(evaluator, params, x, y, dtype=F32, precision="default"):
-    """-> (accuracy, mean loss) over every sample of (x, y)."""
+    """-> (accuracy, mean loss) over every label of (x, y)."""
     xdt = dtype if np.issubdtype(x.dtype, np.floating) else x.dtype
     with precision_scope(dtype, precision):
         c, s = evaluator(params, jnp.asarray(x, xdt), jnp.asarray(y))
-    return float(c) / len(x), float(s) / len(x)
+    return float(c) / y.size, float(s) / y.size
 
 
 def staleness_coefficients(entries, current_round: int):

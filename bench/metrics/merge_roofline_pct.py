@@ -10,18 +10,24 @@ memory-bound at any K).  Device time is that of the `fed_agg` program
 (`kernels/fed_agg._fed_agg_impl`, pad and un-pad included; the row
 gather before it is a program of its own and not counted), the mean over
 the chips.  K of each merge is the round's `aggregated_updates`.
-Moves round_s."""
+
+On several chips the reader reads nothing: the P-sharded merge
+(`kernels/fed_agg.fed_agg_sharded`) runs as eager `shard_map` programs,
+which JAX names `jit__unknown`, a name that does not tell the merge from
+any other eager program.  Moves round_s."""
 from fedbench import work, xtrace
 
-MODULES = r"jit__fed_agg_impl|jit_fed_agg_sharded.*"
+MODULES = r"jit__fed_agg_impl"
 
 
 def read(ctx):
     ks = [r.aggregated_updates for s in ctx.studies for r in s.rounds
           if r.aggregated_updates > 0]
+    if ctx.chips > 1 or not ks:
+        return None
     runs = [xtrace.module_ns(ctx.trace, c, MODULES)
             for c in range(ctx.chips)]
-    if not ks or any(len(r) != len(ks) for r in runs):
+    if any(len(r) != len(ks) for r in runs):
         return None
     device_s = sum(map(sum, runs)) / ctx.chips / 1e9
     p = ctx.config["params"]

@@ -6,7 +6,9 @@ cohort of 4, 5 rounds, and the FEMNIST CNN narrowed to an FC width of 64
 (the LSTM to 16 hidden units), with the program's timed path broken
 underneath.  The limits are the cell's own, from bench/limits/.
 """
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -175,3 +177,108 @@ def test_control_is_not_correct():
     nums = bench.checker().numbers(bench.cap, impl="control")
     assert any(nums[k] > cell.limits[k] for k in checks.NUMBERS
                if cell.limits[k] is not None), nums
+
+
+# the check as it fits a chip too small for blocks of 8 clients and for
+# device-held captures (harness.check_sizes): the reference trains one
+# client at a time, the probes keep host copies
+def small_chip(monkeypatch, block=1, host=True):
+    monkeypatch.setattr(harness, "check_sizes",
+                        lambda cfg, traffic, device: (block, host))
+
+
+@pytest.mark.parametrize("workload", sorted(CONFIGS))
+def test_sound_run_is_correct_on_a_small_chip(monkeypatch, workload):
+    small_chip(monkeypatch)
+    result = run(workload=workload)
+    assert result["correct"], result["checks"]
+
+
+def test_probes_on_the_host_read_the_same_numbers(monkeypatch):
+    """Host copies hold the same values as the device arrays they copy, so
+    the six numbers do not move."""
+    device = run()
+    small_chip(monkeypatch, block=8)
+    host = run()
+    assert host["checks"] == device["checks"]
+
+
+@pytest.mark.parametrize("fault, workload", [
+    ("frozen", "femnist-fedlesscan-s30"),
+    ("frozen", "shakespeare-fedlesscan-s30"),
+    ("half_batch", "shakespeare-fedlesscan-s30")])
+def test_broken_training_is_not_correct_on_a_small_chip(
+        monkeypatch, fault, workload):
+    small_chip(monkeypatch)
+    if fault == "frozen":
+        frozen(monkeypatch)
+    else:
+        _break_group_fn(monkeypatch, half_batch)
+    result = run(workload=workload)
+    assert not result["correct"], result["checks"]
+
+
+def test_control_is_not_correct_on_a_small_chip(monkeypatch):
+    from fedbench.probes import HostRows
+    small_chip(monkeypatch)
+    cell = Cell(WORKLOAD)
+    bench = harness.Bench(cell, TRAFFIC, CONFIG)
+    assert bench.block == 1 and bench.cap.host
+    bench.prepare(5)
+    bench.study()
+    assert all(isinstance(b, HostRows) for rows in bench.cap.rows.values()
+               for b, _ in rows.values())
+    nums = bench.checker().numbers(bench.cap, impl="control")
+    assert any(nums[k] > cell.limits[k] for k in checks.NUMBERS
+               if cell.limits[k] is not None), nums
+
+
+GB = 10 ** 9
+
+
+@pytest.mark.parametrize("workload, config, traffic, chip, sizes", [
+    # the committed cells on a 16 GB chip: today's check
+    ("femnist-fedlesscan-s30", {}, {}, 16 * GB, (8, False)),
+    ("shakespeare-fedlesscan-s30", {}, {}, 16 * GB, (8, False)),
+    # a device that reports no memory (the CPU)
+    ("femnist-fedlesscan-s30", {}, {}, None, (8, False)),
+    # FEMNIST's cohort of 128 over 4 chips: 4 P (2 x 32 + 3) = 1.77 GB
+    # of captures per chip, under an eighth of 16 GB
+    ("femnist-fedlesscan-s30", {}, {"cohort": 128, "executor_devices": 4},
+     16 * GB, (8, False)),
+    # a client of 528 M parameters under Adam: 8.45 GB of training state,
+    # over a quarter of the chip alone; its captures pass an eighth
+    ("femnist-fedlesscan-s30", {"params": 528_000_000},
+     {"cohort": 4, "executor_devices": 4}, 16 * GB, (1, True)),
+    # 50 M parameters under Adam: 0.8 GB each, 5 fit in 4 GB
+    ("femnist-fedlesscan-s30", {"params": 50_000_000}, {}, 16 * GB,
+     (5, True)),
+])
+def test_check_sizes(monkeypatch, workload, config, traffic, chip, sizes):
+    monkeypatch.setattr(harness, "chip_bytes", lambda device: chip)
+    cell = Cell(workload)
+    assert harness.check_sizes(harness.merged(cell.config, config),
+                               harness.merged(cell.traffic, traffic),
+                               None) == sizes
+
+
+def test_four_chip_cell_on_four_virtual_devices():
+    """FEMNIST's cell on four CPU devices at this file's size, its cohort
+    and merge sharded over them: a sound run is correct; the merge with
+    the exchange between chips left out, and local steps that return
+    their params, are not."""
+    import json
+    import subprocess
+    import sys
+    script = Path(__file__).with_name("four_devices.py")
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    out = subprocess.run([sys.executable, str(script), "none",
+                          "no_exchange", "frozen"], env=env,
+                         capture_output=True, text=True, timeout=1200)
+    assert out.returncode == 0, out.stderr[-4000:]
+    runs = {r["fault"]: r for r in map(json.loads,
+                                       out.stdout.strip().splitlines())}
+    assert {r["device_count"] for r in runs.values()} == {4}
+    assert runs["none"]["correct"], runs["none"]["checks"]
+    assert not runs["no_exchange"]["correct"], runs["no_exchange"]["checks"]
+    assert not runs["frozen"]["correct"], runs["frozen"]["checks"]

@@ -98,3 +98,40 @@ def test_readers_return_nothing_when_there_is_nothing_to_read(ctx):
     for name in ("train_device_ms", "merge_roofline_pct",
                  "dispatch_host_ms", "eval_host_ms", "schedule_host_ms"):
         assert metric(name).read(ctx) is None
+
+
+@pytest.fixture
+def ctx4(ctx):
+    """The same window on four chips.  Each chip trains for 300 ms per
+    round; each of the two merges runs as four `jit__unknown` programs
+    per chip (the P-sharded merge's eager shard_map), 1 ms in all."""
+    def chip():
+        q = MS / 4
+        return ([("jit_one_client", 100 * MS, 400 * MS),
+                 ("jit__take", 400 * MS, 402 * MS)]
+                + [("jit__unknown", 402 * MS + i * q, 402 * MS + (i + 1) * q)
+                   for i in range(4)]
+                + [("jit_one_client", 500 * MS, 800 * MS)]
+                + [("jit__unknown", 801 * MS + i * q, 801 * MS + (i + 1) * q)
+                   for i in range(4)])
+    ctx.trace = xtrace.Trace(4, [chip() for _ in range(4)], [[]] * 4,
+                             ctx.trace.spans)
+    ctx.chips = 4
+    return ctx
+
+
+def test_readers_on_four_chips(ctx4):
+    assert metric("train_device_ms").read(ctx4) == pytest.approx(300.0)
+    flops = 29 * 227 * 5 * 103_271_424
+    assert metric("train_mfu").read(ctx4) == pytest.approx(
+        100 * flops / (4 * 197e12))
+
+
+def test_merge_reader_reads_nothing_on_several_chips(ctx4):
+    # the sharded merge's programs are named `jit__unknown`, as any eager
+    # program is: nothing tells them apart, so no reading
+    assert metric("merge_roofline_pct").read(ctx4) is None
+    ctx4.trace.modules = [m + [("jit__fed_agg_impl", 402 * MS, 404 * MS),
+                               ("jit__fed_agg_impl", 801 * MS, 803 * MS)]
+                          for m in ctx4.trace.modules]
+    assert metric("merge_roofline_pct").read(ctx4) is None
