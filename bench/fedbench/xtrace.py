@@ -1,7 +1,9 @@
 """From a profiler trace (.xplane.pb) to the events the metric readers
 use: each chip's XLA module runs, each chip's XLA ops where the trace
-holds them, and the benchmark's own host spans (`bench.*`), all on the
-profiler's clock in nanoseconds."""
+holds them, the benchmark's own host spans (`bench.*`), and on several
+chips, where the trace ties module runs to their launches on the host (a
+`run_id` stat on both), the launches, all on the profiler's clock in
+nanoseconds."""
 from __future__ import annotations
 
 import glob
@@ -25,6 +27,11 @@ class Trace:
     # per chip: (op name, start_ns, end_ns); empty when not traced
     ops: List[List[Tuple[str, float, float]]]
     spans: Dict[str, List[Interval]] = field(default_factory=dict)
+    # on several chips, per chip: the run id of each module run, in the
+    # order of `modules` (None where the event has none)
+    run_ids: List[List[Optional[int]]] = field(default_factory=list)
+    # on several chips: run id -> start of the host event that launched it
+    launches: Dict[int, float] = field(default_factory=dict)
 
     @property
     def window(self) -> Interval:
@@ -51,7 +58,9 @@ def load(path: str, chips: int) -> Trace:
     pd = ProfileData.from_file(path)
     modules: List[list] = [[] for _ in range(chips)]
     ops: List[list] = [[] for _ in range(chips)]
+    run_ids: List[list] = [[] for _ in range(chips)]
     spans: Dict[str, List[Interval]] = {}
+    launches: Dict[int, float] = {}
     for plane in pd.planes:
         m = re.fullmatch(r"/device:TPU:(\d+)", plane.name)
         if m and int(m.group(1)) < chips:
@@ -64,18 +73,31 @@ def load(path: str, chips: int) -> Trace:
                 for e in line.events:
                     dest.append((_HASH.sub("", e.name), e.start_ns,
                                  e.end_ns))
+                    if dest is modules[i] and chips > 1:
+                        run_ids[i].append(_run_id(e))
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith(SPAN_PREFIX):
                         spans.setdefault(e.name, []).append(
                             (e.start_ns, e.end_ns))
+                    rid = _run_id(e) if chips > 1 else None
+                    if rid is not None and e.start_ns < launches.get(
+                            rid, float("inf")):
+                        launches[rid] = e.start_ns
     if not any(modules):
         raise RuntimeError("the trace holds no device module on the chips "
                            "used")
     for lst in spans.values():
         lst.sort()
-    return Trace(chips, modules, ops, spans)
+    return Trace(chips, modules, ops, spans, run_ids, launches)
+
+
+def _run_id(event) -> Optional[int]:
+    for name, value in event.stats:
+        if name == "run_id":
+            return int(value)
+    return None
 
 
 def clip(intervals, window: Interval) -> List[Interval]:
@@ -109,6 +131,28 @@ def module_ns(trace: Trace, chip: int, pattern: str) -> List[float]:
     return [e - s for n, s, e in trace.modules[chip]
             if rx.fullmatch(n) and s >= trace.window[0]
             and e <= trace.window[1]]
+
+
+def launched_runs(trace: Trace, chip: int, names, span: Interval
+                  ) -> Optional[List[Tuple[str, float, float]]]:
+    """The window's runs on the chip of modules named in `names` that were
+    launched inside `span`, in the order they ran, each paired with its
+    launch by run id; None where the trace does not hold the launch of
+    every such run."""
+    ids = trace.run_ids[chip] if chip < len(trace.run_ids) else []
+    if len(ids) != len(trace.modules[chip]):
+        return None
+    out = []
+    for (n, s, e), rid in zip(trace.modules[chip], ids):
+        if n not in names:
+            continue
+        at = trace.launches.get(rid)
+        if at is None:
+            return None
+        if (span[0] <= at < span[1] and s >= trace.window[0]
+                and e <= trace.window[1]):
+            out.append((n, s, e))
+    return sorted(out, key=lambda r: r[1])
 
 
 def span_ns(trace: Trace, name: str) -> Optional[float]:

@@ -2,10 +2,14 @@
 test's size, with the cohort and the merge sharded over the four
 (`executor_devices` and `merge_devices` 4), one run per planted fault
 named on the command line ("none" for a sound run); prints one JSON line
-per run: {"fault", "correct", "device_count", "checks"}.  Run it in a process of its own, since the device count is
-fixed when JAX starts:
+per run: {"fault", "host", "correct", "device_count", "checks"}.
+`--round t` makes t the checked round instead of the seed's draw, and
+`--host` has the probes keep host copies, for the runs named after it.
+Run it in a process of its own, since the device count is fixed when JAX
+starts:
 
     python3 bench/tests/four_devices.py none no_exchange frozen
+    python3 bench/tests/four_devices.py --round 0 none --host none
 """
 import json
 import os
@@ -45,7 +49,7 @@ def frozen_task(harness):
                                                  0.0}))
 
 
-def main(faults) -> int:
+def main(argv) -> int:
     import jax
     import repro.kernels
     from fedbench import harness
@@ -57,7 +61,20 @@ def main(faults) -> int:
             super().__init__(workload)
             self.chips = 4
     harness.Cell = FourChips
-    for fault in faults:
+    args, host = list(argv), False
+    while args:
+        fault = args.pop(0)
+        if fault == "--round":
+            t = int(args.pop(0))
+            draw = harness.sample_rounds
+            harness.sample_rounds = lambda traffic, seed: (
+                t, draw(traffic, seed)[1])
+            continue
+        if fault == "--host":
+            host = True
+            sizes = harness.check_sizes
+            harness.check_sizes = lambda *a: (sizes(*a)[0], True)
+            continue
         saved = {}
         if fault == "no_exchange":
             saved[(repro.kernels, "fed_agg_sharded")] = \
@@ -77,7 +94,8 @@ def main(faults) -> int:
         finally:
             for (obj, attr), value in saved.items():
                 setattr(obj, attr, value)
-        print(json.dumps({"fault": fault, "correct": res["correct"],
+        print(json.dumps({"fault": fault, "host": host,
+                          "correct": res["correct"],
                           "device_count": res["device"]["count"],
                           "checks": res["checks"]}), flush=True)
     return 0

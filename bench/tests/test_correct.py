@@ -262,23 +262,38 @@ def test_check_sizes(monkeypatch, workload, config, traffic, chip, sizes):
                                None) == sizes
 
 
-def test_four_chip_cell_on_four_virtual_devices():
-    """FEMNIST's cell on four CPU devices at this file's size, its cohort
-    and merge sharded over them: a sound run is correct; the merge with
-    the exchange between chips left out, and local steps that return
-    their params, are not."""
+def four_devices(*args):
+    """Runs of four_devices.py in a process of its own -> (fault, host
+    copies) -> its result line."""
     import json
     import subprocess
     import sys
     script = Path(__file__).with_name("four_devices.py")
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    out = subprocess.run([sys.executable, str(script), "none",
-                          "no_exchange", "frozen"], env=env,
+    out = subprocess.run([sys.executable, str(script), *args], env=env,
                          capture_output=True, text=True, timeout=1200)
     assert out.returncode == 0, out.stderr[-4000:]
-    runs = {r["fault"]: r for r in map(json.loads,
-                                       out.stdout.strip().splitlines())}
-    assert {r["device_count"] for r in runs.values()} == {4}
-    assert runs["none"]["correct"], runs["none"]["checks"]
-    assert not runs["no_exchange"]["correct"], runs["no_exchange"]["checks"]
-    assert not runs["frozen"]["correct"], runs["frozen"]["checks"]
+    runs = [json.loads(line) for line in out.stdout.strip().splitlines()]
+    assert {r["device_count"] for r in runs} == {4}
+    return {(r["fault"], r["host"]): r for r in runs}
+
+
+def test_four_chip_cell_on_four_virtual_devices():
+    """FEMNIST's cell on four CPU devices at this file's size, its cohort
+    and merge sharded over them: a sound run is correct; the merge with
+    the exchange between chips left out, and local steps that return
+    their params, are not."""
+    runs = four_devices("none", "no_exchange", "frozen")
+    assert runs[("none", False)]["correct"], runs[("none", False)]["checks"]
+    for fault in ("no_exchange", "frozen"):
+        assert not runs[(fault, False)]["correct"], runs[(fault, False)]
+
+
+def test_round_0_on_four_virtual_devices_with_host_copies():
+    """The checked round is the first, trained from the initial params:
+    the sound run is correct, and reads the same numbers where the
+    probes keep host copies of each chip's rows."""
+    runs = four_devices("--round", "0", "none", "--host", "none")
+    sound = runs[("none", False)]
+    assert sound["correct"], sound["checks"]
+    assert runs[("none", True)]["checks"] == sound["checks"]
