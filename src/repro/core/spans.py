@@ -16,7 +16,10 @@ The counters are plain process-wide integers, always on:
 * ``staged_bytes`` — cohort tensors staged host→device by the executor;
 * ``host_syncs`` — blocking device→host reads (one per ``fl.sync``);
 * ``events`` — event-queue pops;
-* ``compiles`` — new executor dispatch shapes (`compile_count` steps).
+* ``compiles`` — new executor dispatch shapes (`compile_count` steps);
+* ``fused_adam_steps`` — per-client Adam steps of dense weights taken by
+  `kernels.dense_adam` (bucket × local steps × engaged weights, counted
+  at each dispatch).
 """
 from __future__ import annotations
 
@@ -27,7 +30,8 @@ import jax
 
 _COUNTERS: Dict[str, int] = {
     "materialize_bytes": 0, "materialize_rows": 0, "loss_syncs": 0,
-    "staged_bytes": 0, "host_syncs": 0, "events": 0, "compiles": 0}
+    "staged_bytes": 0, "host_syncs": 0, "events": 0, "compiles": 0,
+    "fused_adam_steps": 0}
 
 
 def counters() -> Dict[str, int]:

@@ -52,15 +52,30 @@ read-back.  ``0`` blocks right here until the trained stack is ready.
 Virtual time never reads the wall clock, so traces are byte-identical
 either way.
 
+Fused dense Adam: where the task's optimizer is Adam without weight
+decay and FedProx's ``mu`` is 0, each dense weight of at least
+``FUSED_ADAM_MIN_BYTES`` per client (FEMNIST's fc1) takes its Adam step
+in `kernels.dense_adam`, inside the backward pass.  The step reads it
+through a `models.small.DenseTap`, whose backward hands the kernel the
+layer's input and the cotangent of its pre-activation: in one pass over
+W, m and v the kernel sums the input's cotangent ``dy·Wᵀ``, forms the
+gradient ``hᵀ·dy`` tile by tile and updates W, m and v in place, and
+autodiff returns the stepped weight and moments as the tap's
+cotangents; ``optimizer.update`` takes every other leaf.  The choice is
+made at trace time from the optimizer, ``mu`` and the shapes; anything
+else compiles the plain step.
+
 Profiler spans (core/spans.py): ``fl.stage`` (host gather ``fl.gather``
 and host→device ``fl.put``), ``fl.dispatch`` (the enqueue of the
-group-train and flatten programs), ``fl.package`` and ``fl.sync`` on
-the loss fetch and the overlap-off block; inside the group-train
-program the local step's parts carry the named scopes ``loss_grad``,
-``proximal`` and ``optimizer``.
+group-train and flatten programs; ``fused_adam`` counts the weights on
+the fused path, and the ``fused_adam_steps`` counter their steps),
+``fl.package`` and ``fl.sync`` on the loss fetch and the overlap-off
+block; inside the group-train program the local step's parts carry the
+named scopes ``loss_grad``, ``proximal`` and ``optimizer``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -73,10 +88,92 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..analysis import gates
 from ..core import spans
 from ..core.device_batch import DeviceUpdateBatch, pipeline_enabled
+from ..kernels import ops
+from ..models.small import DenseTap, dense_layers
 from ..optim import apply_updates, proximal_grad
+from ..optim.optimizers import bias_corrections
 from ..sharding.rules import cohort_spec
 
 Pytree = Any
+
+# a dense weight of at least this many bytes per client takes its Adam
+# step in kernels/dense_adam.py, where the optimizer allows it
+FUSED_ADAM_MIN_BYTES = 4 * 2 ** 20
+
+
+def fused_adam_layers(optimizer, mu: float, params: Pytree) -> tuple:
+    """Key paths of the dense layers whose weight takes the fused Adam
+    kernel: Adam without weight decay, no proximal term, and a float32
+    weight of at least FUSED_ADAM_MIN_BYTES.  Decided from the optimizer,
+    ``mu`` and the shapes alone; empty where any of them rules it out."""
+    hyper = optimizer.adam
+    if hyper is None or hyper.weight_decay or mu:
+        return ()
+    return tuple(path for path, layer in dense_layers(params)
+                 if layer["w"].dtype == jnp.float32
+                 and layer["w"].size * 4 >= FUSED_ADAM_MIN_BYTES)
+
+
+def _weight(tree: Pytree, path: tuple):
+    for key in path:
+        tree = tree[key]
+    return tree["w"]
+
+
+def _with_weight(tree: Pytree, path: tuple, w) -> Pytree:
+    """A copy of ``tree`` with the dense layer at ``path``'s ``w`` set."""
+    if not path:
+        return {**tree, "w": w}
+    return {**tree, path[0]: _with_weight(tree[path[0]], path[1:], w)}
+
+
+def _adam_step(hyper, x, dy, w, state):
+    """A `DenseTap` update: the input's cotangent and this step's Adam
+    update of ``w`` from ``x`` and ``dy``, in `kernels.dense_adam`;
+    ``state`` is (m, v, the step's two bias corrections)."""
+    m, v, bc = state
+    dx, w, m, v = ops.dense_adam(
+        x, dy, w, m, v, bc[0], bc[1], lr=hyper.learning_rate, b1=hyper.b1,
+        b2=hyper.b2, eps=hyper.eps)
+    return dx, w, (m, v, bc)
+
+
+def _tapped(params: Pytree, opt_state: Pytree, paths: tuple,
+            hyper) -> Pytree:
+    """``params`` with each dense weight at ``paths`` wrapped in a
+    `DenseTap` whose backward pass takes this step's Adam update."""
+    if not paths:
+        return params
+    t = (opt_state["count"] + 1).astype(jnp.float32)
+    bc = jnp.stack(bias_corrections(t, hyper.b1, hyper.b2))
+    update = functools.partial(_adam_step, hyper)
+    for path in paths:
+        params = _with_weight(params, path, DenseTap(
+            _weight(params, path), (_weight(opt_state["m"], path),
+                                    _weight(opt_state["v"], path), bc),
+            update))
+    return params
+
+
+def _fused_adam_update(optimizer, paths: tuple, grads: Pytree,
+                       state: Pytree, params: Pytree):
+    """One Adam step -> (params, state): ``optimizer.update`` on every
+    leaf but the weights at ``paths``, whose `DenseTap` cotangents are
+    already their stepped weight and moments."""
+    rest = lambda tree: functools.reduce(
+        lambda t, path: _with_weight(t, path, None), paths, tree)
+    updates, new = optimizer.update(
+        rest(grads), {"count": state["count"], "m": rest(state["m"]),
+                      "v": rest(state["v"])}, rest(params))
+    params_out = apply_updates(rest(params), updates)
+    m_out, v_out = new["m"], new["v"]
+    for path in paths:
+        tap = _weight(grads, path)
+        m, v, _ = tap.state
+        params_out = _with_weight(params_out, path, tap.w)
+        m_out = _with_weight(m_out, path, m)
+        v_out = _with_weight(v_out, path, v)
+    return params_out, {"count": new["count"], "m": m_out, "v": v_out}
 
 
 def _batch_indices(n: int, batch_size: int, epochs: int,
@@ -188,6 +285,7 @@ class VectorizedExecutor:
 
         def one_client(global_params, xs, ys, ms):
             opt_state = optimizer.init(global_params)
+            fused = fused_adam_layers(optimizer, mu, global_params)
 
             # the named scopes label the step's parts in the HLO
             # metadata (and so in a device trace); the program is
@@ -197,13 +295,18 @@ class VectorizedExecutor:
                 x, y, m = batch
                 with jax.named_scope("loss_grad"):
                     loss, grads = jax.value_and_grad(masked_loss)(
-                        params, x, y, m)
+                        _tapped(params, opt_state, fused, optimizer.adam),
+                        x, y, m)
                 with jax.named_scope("proximal"):
                     grads = proximal_grad(grads, params, global_params, mu)
                 with jax.named_scope("optimizer"):
-                    updates, opt_state = optimizer.update(grads, opt_state,
-                                                          params)
-                    params = apply_updates(params, updates)
+                    if fused:
+                        params, opt_state = _fused_adam_update(
+                            optimizer, fused, grads, opt_state, params)
+                    else:
+                        updates, opt_state = optimizer.update(
+                            grads, opt_state, params)
+                        params = apply_updates(params, updates)
                 return (params, opt_state), loss
 
             # XLA:CPU executes while-loops serially with poor fusion —
@@ -322,8 +425,12 @@ class VectorizedExecutor:
             # params committed to one device, which the dispatch refuses
             global_params = jax.device_put(global_params,
                                            NamedSharding(self.mesh, P()))
+        fused = len(fused_adam_layers(self.task.optimizer, mu,
+                                      global_params))
+        spans.count("fused_adam_steps", xs.shape[0] * xs.shape[1] * fused)
         with spans.span("fl.dispatch", round=round_number,
-                        bucket=int(xs.shape[0]), new_shape=int(new_shape)):
+                        bucket=int(xs.shape[0]), new_shape=int(new_shape),
+                        fused_adam=fused):
             out_params, losses = self._group_fn(mu)(global_params, xs, ys,
                                                     ms)
             if not flatten:

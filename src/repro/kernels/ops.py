@@ -26,6 +26,7 @@ from .compress import int8_encode as _int8_encode
 from .compress import topk_decode as topk_decode  # noqa: F401 (re-export)
 from .compress import topk_encode as _topk_encode
 from .compress import topk_mask as _topk_mask
+from .dense_adam import dense_adam as _dense_adam
 from .fed_agg import fed_agg as _fed_agg
 from .fed_agg import fed_agg_apply as _fed_agg_apply
 from .fed_agg import fed_agg_apply_sharded as _fed_agg_apply_sharded
@@ -104,6 +105,15 @@ def fed_agg_apply_sharded(updates: jnp.ndarray, coeffs: jnp.ndarray,
         updates, coeffs, params, m, v, lr, mix, b1, b2, eps, opt=opt,
         mesh=mesh, tile_p=tile_p,
         interpret=resolve_interpret(interpret))
+
+
+def dense_adam(h: jnp.ndarray, dy: jnp.ndarray, w: jnp.ndarray,
+               m: jnp.ndarray, v: jnp.ndarray, bc1, bc2, *, lr: float,
+               b1: float, b2: float, eps: float, tm: int = 256,
+               tn: int = 2048, interpret: Optional[bool] = None):
+    return _dense_adam(h, dy, w, m, v, bc1, bc2, lr=lr, b1=b1, b2=b2,
+                       eps=eps, tm=tm, tn=tn,
+                       interpret=resolve_interpret(interpret))
 
 
 def int8_encode(x: jnp.ndarray, chunk: int = 256, tile_r: int = 8,

@@ -53,6 +53,20 @@ def fed_agg_apply_ref(updates: jnp.ndarray, coeffs: jnp.ndarray,
     return g + lr * step, m, v, jnp.sqrt(jnp.sum(delta * delta))
 
 
+# ------------------------------------------------------------ dense_adam
+def dense_adam_ref(h: jnp.ndarray, dy: jnp.ndarray, w: jnp.ndarray,
+                   m: jnp.ndarray, v: jnp.ndarray, bc1, bc2, *, lr: float,
+                   b1: float, b2: float, eps: float):
+    """Oracle for the fused dense-weight backward and Adam step: the
+    input's cotangent dy·Wᵀ, the gradient hᵀ·dy, then `optim.adam`'s
+    moment updates and step.  Returns (dx, w, m, v)."""
+    dx = dy @ w.T
+    g = h.T @ dy
+    m = b1 * m + (1 - b1) * g
+    v = b2 * v + (1 - b2) * jnp.square(g)
+    return dx, w + (-lr * (m / bc1) / (jnp.sqrt(v / bc2) + eps)), m, v
+
+
 # ------------------------------------------------------------ compress
 def int8_encode_ref(x: jnp.ndarray, chunk: int = 256):
     """Per-chunk int8 quantization oracle: scale = absmax/127 (1.0 for

@@ -14,9 +14,16 @@ input gradient ``dg @ wx.T`` is likewise one product after it.  The weight
 and bias gradients stay per-step sums in the loop, in the order plain
 autodiff of the per-step LSTM adds them, so training rounds exactly as it
 does.
+
+A dense weight wrapped in a `DenseTap` takes its optimizer step inside
+the backward pass: `_dense` hands the tap's ``update`` the layer's input
+and the cotangent of its pre-activation, and autodiff returns the stepped
+weight and state in the gradient tree (the executor's fused Adam step,
+`kernels/dense_adam.py`).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -40,8 +47,66 @@ def _dense_init(rng, n_in, n_out):
             "b": jnp.zeros((n_out,))}
 
 
+@jax.tree_util.register_pytree_node_class
+class DenseTap:
+    """A dense weight that takes its optimizer step in the backward pass.
+
+    `_dense` multiplies by ``w``.  In the backward pass
+    ``update(x, dy, w, state)`` gets the layer's input ``x`` (B, n_in)
+    and the cotangent of its pre-activation ``dy`` (B, n_out), whose
+    product ``xᵀ·dy`` is ``w``'s gradient, and returns the input's
+    cotangent ``dy·wᵀ`` and the new ``(w, state)``.  Autodiff hands
+    those back as the cotangents of ``w`` and ``state``: the caller reads
+    the stepped weight and state from the gradient tree.  ``update`` is
+    static (the pytree's aux data).  A tapped weight is read by one
+    `_dense` call."""
+
+    def __init__(self, w, state, update):
+        self.w, self.state, self.update = w, state, update
+
+    def tree_flatten(self):
+        return (self.w, self.state), self.update
+
+    @classmethod
+    def tree_unflatten(cls, update, children):
+        return cls(*children, update)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _stepped_matmul(update, x, w, state):
+    return x @ w
+
+
+def _stepped_matmul_fwd(update, x, w, state):
+    return x @ w, (x, w, state)
+
+
+def _stepped_matmul_bwd(update, res, dy):
+    x, w, state = res
+    return update(x, dy, w, state)
+
+
+_stepped_matmul.defvjp(_stepped_matmul_fwd, _stepped_matmul_bwd)
+
+
 def _dense(p, x):
-    return x @ p["w"] + p["b"]
+    w = p["w"]
+    if isinstance(w, DenseTap):
+        return _stepped_matmul(w.update, x, w.w, w.state) + p["b"]
+    return x @ w + p["b"]
+
+
+def dense_layers(params: Pytree, path=()):
+    """(key path, layer) of every dense layer in ``params``: a dict of a
+    2-D ``w`` and a ``b``, as `_dense_init` makes them."""
+    if not isinstance(params, dict):
+        return
+    w = params.get("w")
+    if set(params) == {"w", "b"} and getattr(w, "ndim", None) == 2:
+        yield path, params
+        return
+    for key, sub in params.items():
+        yield from dense_layers(sub, path + (key,))
 
 
 def _conv_init(rng, kh, kw, cin, cout):

@@ -9,7 +9,7 @@ which is the gradient of the paper's proximal term mu/2 ||w - w_global||^2.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +17,20 @@ import jax.numpy as jnp
 Pytree = Any
 
 
+class AdamHyper(NamedTuple):
+    learning_rate: float
+    b1: float
+    b2: float
+    eps: float
+    weight_decay: float
+
+
 class Optimizer(NamedTuple):
     init: Callable[[Pytree], Pytree]
     update: Callable[[Pytree, Pytree, Pytree], Tuple[Pytree, Pytree]]
+    # Adam's hyperparameters, for code that applies its step to a leaf
+    # itself (kernels/dense_adam.py); None for every other optimizer
+    adam: Optional[AdamHyper] = None
 
 
 def apply_updates(params: Pytree, updates: Pytree) -> Pytree:
@@ -86,8 +97,7 @@ def adam(learning_rate: float, b1: float = 0.9, b2: float = 0.999,
         v = jax.tree_util.tree_map(
             lambda v_, g: b2 * v_ + (1 - b2) * jnp.square(g.astype(jnp.float32)),
             state["v"], grads)
-        bc1 = 1 - b1 ** cf
-        bc2 = 1 - b2 ** cf
+        bc1, bc2 = bias_corrections(cf, b1, b2)
 
         def step(m_, v_, p):
             upd = -learning_rate * (m_ / bc1) / (jnp.sqrt(v_ / bc2) + eps)
@@ -98,7 +108,13 @@ def adam(learning_rate: float, b1: float = 0.9, b2: float = 0.999,
         updates = jax.tree_util.tree_map(step, m, v, params)
         return updates, {"count": count, "m": m, "v": v}
 
-    return Optimizer(init, update)
+    return Optimizer(init, update,
+                     AdamHyper(learning_rate, b1, b2, eps, weight_decay))
+
+
+def bias_corrections(t, b1: float, b2: float):
+    """Adam's (1 − b1^t, 1 − b2^t) at step ``t`` (float32, from 1)."""
+    return 1 - b1 ** t, 1 - b2 ** t
 
 
 def adamw(learning_rate: float, weight_decay: float = 0.01, **kw) -> Optimizer:

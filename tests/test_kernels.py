@@ -4,11 +4,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import (APPLY_OPTS, fed_agg, fed_agg_apply,
+from repro.kernels import (APPLY_OPTS, dense_adam, fed_agg, fed_agg_apply,
                            fed_agg_apply_sharded, fed_agg_sharded,
                            flash_attention, ssd_scan, topk_mask)
-from repro.kernels.ref import (fed_agg_apply_ref, fed_agg_ref,
-                               flash_attention_ref, ssd_ref, topk_ref)
+from repro.kernels.ref import (dense_adam_ref, fed_agg_apply_ref,
+                               fed_agg_ref, flash_attention_ref, ssd_ref,
+                               topk_ref)
+from repro.optim.optimizers import bias_corrections
 from repro.launch.mesh import make_host_mesh
 
 RNG = np.random.default_rng(0)
@@ -190,6 +192,79 @@ def test_ssd_state_continuity_vs_model_path():
     y1 = ssd_scan(x, a, B, C, chunk=32)
     y2, _ = ssd_chunked(x, a, B, C, chunk=64)
     np.testing.assert_allclose(y1, y2, rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------- dense_adam
+ADAM = dict(lr=1e-3, b1=0.9, b2=0.999, eps=1e-8)
+
+
+def _adam_factors(rng, clients, n_in, n_out, batch=10, real=7):
+    """h, dy for ``clients`` of a batch whose rows past ``real`` are
+    masked samples (zero rows of dy), and zero moments."""
+    h = jnp.asarray(rng.normal(size=(clients, batch, n_in)), jnp.float32)
+    dy = rng.normal(size=(clients, batch, n_out)) * 1e-2
+    dy[:, real:] = 0.0
+    w = jnp.asarray(rng.normal(size=(clients, n_in, n_out)) * 0.05,
+                    jnp.float32)
+    zeros = jnp.zeros_like(w)
+    return h, jnp.asarray(dy, jnp.float32), w, zeros, zeros
+
+
+def _assert_state_close(got, want):
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("n_in,n_out,tm,tn", [
+    (3136, 256, 256, 512),     # FEMNIST's n_in (not a multiple of 128)
+    (200, 300, 64, 128),       # an edge tile on both axes
+    (48, 128, 256, 512),       # one tile, smaller than the targets
+    (96, 512, 32, 128),        # dx summed over four column tiles
+])
+def test_dense_adam_matches_ref_over_steps(n_in, n_out, tm, tn):
+    """Three steps of one client: the bias corrections change each step,
+    the moments carry, masked samples add nothing, and dx is taken at
+    each step's old weight."""
+    h, dy, w, m, v = (a[0] for a in _adam_factors(RNG, 1, n_in, n_out))
+    got = want = (w, m, v)
+    for step in range(1, 4):
+        bc1, bc2 = bias_corrections(jnp.float32(step), 0.9, 0.999)
+        dx, *got = dense_adam(h * step, dy, *got, bc1, bc2, **ADAM, tm=tm,
+                              tn=tn)
+        want_dx, *want = dense_adam_ref(h * step, dy, *want, bc1, bc2,
+                                        **ADAM)
+        _assert_state_close([dx, *got], [want_dx, *want])
+
+
+def test_dense_adam_masked_rows_add_nothing():
+    """Rows of dy that are zero (masked samples) leave the step as the
+    real rows alone give it, whatever h holds there, and give zero rows
+    of dx."""
+    h, dy, w, m, v = (a[0] for a in _adam_factors(RNG, 1, 3136, 128))
+    bc1, bc2 = bias_corrections(jnp.float32(1), 0.9, 0.999)
+    full = dense_adam(h, dy, w, m, v, bc1, bc2, **ADAM)
+    real = dense_adam(h[:7], dy[:7], w, m, v, bc1, bc2, **ADAM)
+    _assert_state_close(full[1:], real[1:])
+    np.testing.assert_allclose(np.asarray(full[0][:7]),
+                               np.asarray(real[0]), rtol=1e-5, atol=1e-6)
+    assert not np.any(np.asarray(full[0][7:]))
+
+
+@pytest.mark.parametrize("n_out,tn", [(256, 2048), (300, 128)])
+def test_dense_adam_under_vmap_over_clients(n_out, tn):
+    """Batched over a cohort by vmap (as the executor runs it), with the
+    bias corrections shared: each client's step is its own, also where
+    dx is summed over column tiles (one of them an edge)."""
+    h, dy, w, m, v = _adam_factors(RNG, 3, 3136, n_out)
+    bc1, bc2 = bias_corrections(jnp.float32(2), 0.9, 0.999)
+    step = jax.jit(jax.vmap(lambda *a: dense_adam(*a, bc1, bc2, **ADAM,
+                                                  tn=tn)))
+    got = step(h, dy, w, m + 1e-3, v + 1e-6)
+    for k in range(3):
+        want = dense_adam_ref(h[k], dy[k], w[k], m[k] + 1e-3, v[k] + 1e-6,
+                              bc1, bc2, **ADAM)
+        _assert_state_close([g[k] for g in got], want)
 
 
 # ------------------------------------------------------ interpret mode

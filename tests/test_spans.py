@@ -115,7 +115,7 @@ def _round_of(events, ev):
     ("fl.stage", {"round", "clients", "bucket", "bytes"}),
     ("fl.gather", set()),
     ("fl.put", {"bytes"}),
-    ("fl.dispatch", {"round", "bucket", "new_shape"}),
+    ("fl.dispatch", {"round", "bucket", "new_shape", "fused_adam"}),
     ("fl.package", {"round", "clients"}),
     ("fl.merge", {"round", "k"}),
     ("fl.eval", {"clients", "samples"}),

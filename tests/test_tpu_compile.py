@@ -11,6 +11,8 @@ only one process at a time may load the TPU library, and pytest-xdist
 workers all import this file.  Everything compiles in the test's own
 process.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -89,9 +91,29 @@ def test_topk_encode_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_executor_group_step_compiles_for_v5e(one_chip):
+def test_dense_adam_compiles_for_v5e(one_chip):
+    """The fused Adam step of FEMNIST's fc1 weight, vmapped over the
+    cohort, with its state donated as the local-step loop carries it."""
+    n_in, n_out, batch = 3136, 2048, 10
+    state = _shape(one_chip, (K, n_in, n_out))
+    step = jax.vmap(lambda h, dy, w, m, v, bc1, bc2: ops.dense_adam(
+        h, dy, w, m, v, bc1, bc2, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8,
+        interpret=False), in_axes=(0, 0, 0, 0, 0, None, None))
+    compiled = jax.jit(step, donate_argnums=(2, 3, 4)).lower(
+        _shape(one_chip, (K, batch, n_in)), _shape(one_chip, (K, batch, n_out)),
+        state, state, state, _shape(one_chip, ()),
+        _shape(one_chip, ())).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # W, m and v are updated in place: no copy of a (K, n_in, n_out) state
+    assert f"f32[{K},{n_in},{n_out}]{{2,1,0:T(8,128)}} copy(" not in text
+
+
+def test_executor_group_step_compiles_for_v5e(one_chip, monkeypatch):
     """The vectorized executor's vmap-of-scan cohort step for the FEMNIST
-    CNN at K = 16 (a few local steps), as one chip would run it."""
+    CNN at K = 16 (a few local steps), as one chip would run it: its fc1
+    weight takes the fused Adam kernel, lowered to Mosaic."""
+    from repro.analysis import gates
     from repro.fl.executor import VectorizedExecutor
     from repro.fl.tasks import ClassificationTask, TaskConfig
     from repro.models.small import SMALL_MODELS
@@ -103,6 +125,8 @@ def test_executor_group_step_compiles_for_v5e(one_chip):
         jax.eval_shape(lambda: task.init_params(0)))
     assert sum(l.size for l in jax.tree_util.tree_leaves(params)) == P
     steps, batch = 4, 10
+    # the backend here is the CPU: lower the kernels as the chip would
+    monkeypatch.setenv(gates.PALLAS_INTERPRET, "0")
     compiled = VectorizedExecutor(task)._group_fn(0.0).lower(
         params,
         _shape(one_chip, (K, steps, batch, 28, 28, 1)),
@@ -112,3 +136,9 @@ def test_executor_group_step_compiles_for_v5e(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16 * 1024 ** 3          # one v5e chip's HBM
+    # one kernel per unrolled local step, in the backward pass (whose
+    # name stack prefixes the kernel's name), and no copy of the state
+    text = compiled.as_text()
+    assert len(re.findall(r"%\w*dense_adam\w*[.\d]* = .* custom-call\(",
+                          text)) == steps
+    assert "f32[16,3136,2048]{2,1,0:T(8,128)} copy(" not in text
